@@ -44,10 +44,10 @@ def clip8(x):
 
 def row_epilogue(acc, bias, words, relu):
     """SDP epilogue, per-channel on the M (row) axis: +bias, requant, relu,
-    int8 clip.  ``acc`` (M, N) int32; ``bias``/``words`` (M,) int32."""
-    acc = acc + bias[:, None]
-    m, pre, post = unpack_words(words)
-    out = apply_scale(acc, m[:, None], pre[:, None], post[:, None])
+    int8 clip.  ``acc`` (M, N) int32; ``bias``/``words`` (M,) or (M, 1)
+    int32 (the Pallas kernel passes column blocks)."""
+    acc = acc + bias.reshape(-1, 1)
+    out = apply_scale(acc, *unpack_words(words.reshape(-1, 1)))
     if relu:
         out = jnp.maximum(out, 0)
     return clip8(out)

@@ -163,10 +163,15 @@ PROFILES: Dict[str, BackendProfile] = {
 
 
 def default_backend() -> str:
-    """The profile name for the platform jax will execute on."""
+    """The profile name for the platform jax will execute on.  A platform
+    with no profile raises: costing it as another platform would pick its
+    kernels for hardware it does not have."""
     import jax
     plat = jax.default_backend()
-    return plat if plat in PROFILES else "cpu"
+    if plat not in PROFILES:
+        raise ValueError(f"no backend profile for platform {plat!r}; known: "
+                         f"{', '.join(sorted(PROFILES))}")
+    return plat
 
 
 def resolve_profile(backend: Union[str, BackendProfile, None]) -> BackendProfile:
@@ -282,9 +287,14 @@ def _kernel_cost(kernel: str, k: int, macs: int, n_cols: int,
                    1.0 * streams * weight_elems / prof.bytes_per_cycle) + launch
     if kernel == KERNEL_GEMM_BF16:
         # bf16 operands stream at 2 bytes/elem; accumulate rides the bf16
-        # units when they exist, the f32 units after an upcast otherwise
+        # units when they exist, the f32 units after an upcast otherwise.
+        # The f32 accumulator leaves the GEMM and is read back for the
+        # bias/ReLU/cast epilogue: 8 bytes per output element that the fused
+        # kernel keeps in VMEM.
+        acc_bytes = 8.0 * lanes * n_cols * (weight_elems // max(k, 1))
         return max(cmacs / prof.bf16_rate,
-                   2.0 * streams * weight_elems / prof.bytes_per_cycle) + launch
+                   (2.0 * streams * weight_elems + acc_bytes)
+                   / prof.bytes_per_cycle) + launch
     if kernel == KERNEL_PALLAS_BF16:
         if not prof.pallas_native:
             return float("inf")            # interpret mode: test-only on CPU

@@ -83,9 +83,9 @@ def compressed_psum(grads: Any, residual: Any, mesh: jax.sharding.Mesh,
         return mean, new_res
 
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.shmap import shard_map_norep as shard_map
     spec = jax.tree.map(lambda _: P(), grads)
     res_spec = jax.tree.map(lambda _: P(), residual)
-    fn = shard_map(inner, mesh=mesh, in_specs=((spec, res_spec),),
-                   out_specs=(spec, res_spec))
+    fn = jax.shard_map(inner, mesh=mesh, check_vma=False,
+                       in_specs=((spec, res_spec),),
+                       out_specs=(spec, res_spec))
     return fn((grads, residual))

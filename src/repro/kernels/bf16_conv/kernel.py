@@ -38,7 +38,7 @@ def _bf16_conv_kernel(w_ref, x_ref, bias_ref, o_ref, acc_ref, *,
 
     @pl.when(k == n_k - 1)
     def _done():
-        acc = acc_ref[...] + bias_ref[...][:, None]
+        acc = acc_ref[...] + bias_ref[...]
         if relu:
             acc = jnp.maximum(acc, 0.0)
         o_ref[...] = acc.astype(jnp.bfloat16)
@@ -46,12 +46,13 @@ def _bf16_conv_kernel(w_ref, x_ref, bias_ref, o_ref, acc_ref, *,
 
 def bf16_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array, *,
                    relu: bool = False, block_m: int = 128, block_n: int = 128,
-                   block_k: int = 128, interpret: bool = True) -> jax.Array:
+                   block_k: int = 128, interpret: bool = False) -> jax.Array:
     """``bf16((w @ cols) + bias[:,None])`` with f32 accumulate — channels on rows.
 
     w: (M, K) bfloat16 — weights, M = output channels
     cols: (K, N) bfloat16 — im2col'ed activations, N = output positions P*Q
-    bias: (M,) float32
+    bias: (M,) float32, entering the kernel as an (M, 1) column (see
+    ``int8_conv_gemm``)
     Shapes must be multiples of the block sizes (ops.py pads).
     """
     m, k = w.shape
@@ -66,11 +67,11 @@ def bf16_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_m,), lambda i, j, kk: (i,)),
+            pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.bfloat16),
         # f32 accumulator tile, persistent across the K loop (CACC analogue)
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(w, cols, bias)
+    )(w, cols, bias.reshape(m, 1))

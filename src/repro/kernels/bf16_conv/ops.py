@@ -64,7 +64,7 @@ def conv2d_bf16(x: jax.Array, wq: jax.Array, bias: jax.Array, k: int,
                 stride: int, pad: int, groups: int = 1, relu: bool = False, *,
                 use_kernel: bool = True, block_m: int = 128,
                 block_n: int = 128, block_k: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Fused CONV+SDP: (C,H,W) bf16 -> (K,P,Q) bf16, f32 accumulate.
 
     x (C,H,W) bfloat16; wq (K, C/g*k*k) bfloat16; bias (K,) float32.
@@ -93,7 +93,7 @@ def conv2d_bf16(x: jax.Array, wq: jax.Array, bias: jax.Array, k: int,
 def fc_bf16(x: jax.Array, wq: jax.Array, bias: jax.Array,
             relu: bool = False, *, use_kernel: bool = True,
             block_m: int = 128, block_n: int = 128, block_k: int = 128,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """Fused FC+SDP: flat bf16 input, wq (K_out, Cin) -> (K_out,1,1) bf16."""
     if not use_kernel:
         return fc_bf16_ref(x, wq, bias, relu)
@@ -107,7 +107,7 @@ def conv2d_bf16_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array, k: int,
                       stride: int, pad: int, groups: int = 1,
                       relu: bool = False, *, use_kernel: bool = True,
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True) -> jax.Array:
+                      block_k: int = 128, interpret: bool = False) -> jax.Array:
     """Natively batched fused CONV+SDP: (B,C,H,W) bf16 -> (B,K,P,Q) bf16.
 
     ONE kernel launch serves the whole bucket — the batch rides the Pallas
@@ -141,7 +141,7 @@ def conv2d_bf16_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array, k: int,
 def fc_bf16_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
                   relu: bool = False, *, use_kernel: bool = True,
                   block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """Natively batched fused FC+SDP: (B, Cin) bf16 -> (B, K_out, 1, 1) bf16.
 
     The bucket IS the GEMM N axis: (K_out, Cin) weights stream once against

@@ -49,13 +49,15 @@ def _int8_conv_kernel(w_ref, x_ref, bias_ref, scale_ref, o_ref, acc_ref, *,
 def int8_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array,
                    scale_words: jax.Array, *, relu: bool = False,
                    block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """``clip8(requant((w @ cols) + bias[:,None]))`` — channels on rows.
 
     w: (M, K) int8 — weights, M = output channels
     cols: (K, N) int8 — im2col'ed activations, N = output positions P*Q
     bias: (M,) int32; scale_words: (M,) int32 packed (m,pre,post)
-    Shapes must be multiples of the block sizes (ops.py pads).
+    Shapes must be multiples of the block sizes (ops.py pads).  The per-row
+    vectors enter the kernel as (M, 1) columns: Mosaic tiles a 1-D operand
+    differently from XLA's layout for it and refuses the kernel.
     """
     m, k = w.shape
     k2, n = cols.shape
@@ -69,12 +71,12 @@ def int8_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_m,), lambda i, j, kk: (i,)),
-            pl.BlockSpec((block_m,), lambda i, j, kk: (i,)),
+            pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0)),
+            pl.BlockSpec((block_m, 1), lambda i, j, kk: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
         # int32 accumulator tile, persistent across the K loop (CACC analogue)
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
-    )(w, cols, bias, scale_words)
+    )(w, cols, bias.reshape(m, 1), scale_words.reshape(m, 1))

@@ -72,7 +72,7 @@ def conv2d_int8(x: jax.Array, wq: jax.Array, bias: jax.Array,
                 groups: int = 1, relu: bool = False, *,
                 use_kernel: bool = True, block_m: int = 128,
                 block_n: int = 128, block_k: int = 128,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """Fused CONV+SDP: (C,H,W) int8 -> (K,P,Q) int8, bit-exact vs refops.
 
     x (C,H,W) int8; wq (K, C/g*k*k) int8; bias/words (K,) int32.
@@ -102,7 +102,7 @@ def conv2d_int8(x: jax.Array, wq: jax.Array, bias: jax.Array,
 def fc_int8(x: jax.Array, wq: jax.Array, bias: jax.Array, words: jax.Array,
             relu: bool = False, *, use_kernel: bool = True,
             block_m: int = 128, block_n: int = 128, block_k: int = 128,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool = False) -> jax.Array:
     """Fused FC+SDP: flat int8 input, wq (K_out, Cin) -> (K_out,1,1) int8."""
     if not use_kernel:
         return fc_int8_ref(x, wq, bias, words, relu)
@@ -117,7 +117,7 @@ def conv2d_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
                       groups: int = 1, relu: bool = False, *,
                       use_kernel: bool = True, block_m: int = 128,
                       block_n: int = 128, block_k: int = 128,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool = False) -> jax.Array:
     """Natively batched fused CONV+SDP: (B,C,H,W) int8 -> (B,K,P,Q) int8.
 
     ONE kernel launch serves the whole bucket — the batch rides the Pallas
@@ -154,7 +154,7 @@ def fc_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
                   words: jax.Array, relu: bool = False, *,
                   use_kernel: bool = True, block_m: int = 128,
                   block_n: int = 128, block_k: int = 128,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool = False) -> jax.Array:
     """Natively batched fused FC+SDP: (B, Cin) int8 -> (B, K_out, 1, 1) int8.
 
     The bucket IS the GEMM N axis — the single-image path is a GEMV that

@@ -14,21 +14,10 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh`` where supported.
-
-    ``jax.sharding.AxisType`` only exists from jax 0.5; on 0.4.x meshes are
-    implicitly Auto, so omitting the kwarg is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax-version-portable ``jax.make_mesh`` with Auto axis types."""
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -108,7 +108,6 @@ def decode_attn_sp(q, k_cache, v_cache, pos, mesh, *, sm_scale=None,
     Returns out, or (out, k_cache', v_cache') when k_new is given.
     """
     import numpy as np
-    from repro.distributed.shmap import shard_map_norep as shard_map
     from jax.sharding import PartitionSpec as P
 
     b, h, _, d = q.shape
@@ -156,9 +155,10 @@ def decode_attn_sp(q, k_cache, v_cache, pos, mesh, *, sm_scale=None,
         return (out, kcv, vcv) if with_update else (out,)
 
     zero = jnp.zeros((b, hkv, 1, d), k_cache.dtype)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(qspec, cspec, cspec, qspec, qspec, P()),
-                   out_specs=(qspec, cspec, cspec) if with_update else (qspec,))
+    fn = jax.shard_map(local, mesh=mesh, check_vma=False,
+                       in_specs=(qspec, cspec, cspec, qspec, qspec, P()),
+                       out_specs=((qspec, cspec, cspec) if with_update
+                                  else (qspec,)))
     res = fn(q, k_cache, v_cache,
              k_new if with_update else zero,
              v_new if with_update else zero, pos)

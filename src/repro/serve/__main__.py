@@ -140,4 +140,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -77,7 +77,7 @@ class TestInt8BatchKernelParity:
         got = conv2d_int8_batch(
             jnp.asarray(xs), jnp.asarray(wq), jnp.asarray(bias),
             jnp.asarray(words.view(np.int32)), c["k"], c["stride"],
-            c["pad"], c["groups"], c["relu"])
+            c["pad"], c["groups"], c["relu"], interpret=True)
         want = np.stack([refops.conv_int8(x, wq, bias, words, c["k"],
                                           c["stride"], c["pad"], c["groups"],
                                           c["relu"]) for x in xs])
@@ -93,7 +93,8 @@ class TestInt8BatchKernelParity:
         words = _words(rng, cout, cin * 128 * 128)
         got = fc_int8_batch(jnp.asarray(xs), jnp.asarray(wq),
                             jnp.asarray(bias),
-                            jnp.asarray(words.view(np.int32)), relu=True)
+                            jnp.asarray(words.view(np.int32)), relu=True,
+                            interpret=True)
         want = np.stack([refops.fc_int8(x, wq, bias, words, relu=True)
                          for x in xs])
         np.testing.assert_array_equal(np.asarray(got), want)
@@ -109,7 +110,7 @@ class TestInt8BatchKernelParity:
         got = np.asarray(conv2d_int8_batch(
             jnp.asarray(padded), jnp.asarray(wq), jnp.asarray(bias),
             jnp.asarray(words.view(np.int32)), c["k"], c["stride"],
-            c["pad"], c["groups"], c["relu"]))
+            c["pad"], c["groups"], c["relu"], interpret=True))
         want_live = np.stack([refops.conv_int8(x, wq, bias, words, c["k"],
                                                c["stride"], c["pad"],
                                                c["groups"], c["relu"])
@@ -132,12 +133,13 @@ class TestBf16BatchKernelParity:
         bias = rng.normal(0, 1, cout).astype(np.float32)
         got = np.asarray(conv2d_bf16_batch(
             jnp.asarray(xs), jnp.asarray(wq), jnp.asarray(bias),
-            k, 1, 0, relu=True), np.float32)
+            k, 1, 0, relu=True, interpret=True), np.float32)
         # folding lanes onto the GEMM N axis preserves each column's f32
         # accumulation order -> bit-identical to vmapping the image kernel
         vmapped = np.asarray(jax.vmap(
             lambda x: conv2d_bf16(x, jnp.asarray(wq), jnp.asarray(bias),
-                                  k, 1, 0, relu=True))(jnp.asarray(xs)),
+                                  k, 1, 0, relu=True, interpret=True))(
+                jnp.asarray(xs)),
             np.float32)
         np.testing.assert_array_equal(got, vmapped)
         want = np.stack([refops.conv_bf16(x, wq, bias, k, 1, 0, relu=True)
@@ -153,9 +155,11 @@ class TestBf16BatchKernelParity:
         wq = rng.normal(0, 0.5, (cout, cin)).astype(ml_dtypes.bfloat16)
         bias = rng.normal(0, 1, cout).astype(np.float32)
         got = np.asarray(fc_bf16_batch(jnp.asarray(xs), jnp.asarray(wq),
-                                       jnp.asarray(bias)), np.float32)
+                                       jnp.asarray(bias), interpret=True),
+                         np.float32)
         vmapped = np.asarray(jax.vmap(
-            lambda x: fc_bf16(x, jnp.asarray(wq), jnp.asarray(bias)))(
+            lambda x: fc_bf16(x, jnp.asarray(wq), jnp.asarray(bias),
+                              interpret=True))(
                 jnp.asarray(xs)), np.float32)
         np.testing.assert_array_equal(got, vmapped)
         want = np.stack([refops.fc_bf16(x, wq, bias) for x in xs])

@@ -55,10 +55,15 @@ def tiny_inputs():
 
 
 @pytest.fixture(scope="module")
-def real_ex(tiny_art):
+def real_ex(tiny_art, tiny_inputs):
     """One real executor per backend, shared across cases (compiled programs
-    amortise); each case wraps it in a fresh ``FaultyExecutor``."""
-    return {b: create_executor(b, tiny_art) for b in BACKENDS}
+    amortise); each case wraps it in a fresh ``FaultyExecutor``.  The
+    bucket the batched cases launch is compiled here, so no case's watchdog
+    has to cover a compile, whichever cases ran before it."""
+    exs = {b: create_executor(b, tiny_art) for b in BACKENDS}
+    for ex in exs.values():
+        ex.run_batch(tiny_inputs)
+    return exs
 
 
 @pytest.fixture(scope="module")

@@ -76,7 +76,7 @@ class TestKernelParitySweep:
                 jnp.asarray(words.view(np.int32)), k, stride, pad, groups, relu)
         tiled = _conv_int8(*args, perfmodel.KERNEL_GEMM_TILED)
         np.testing.assert_array_equal(np.asarray(tiled), want)
-        pallas = conv2d_int8(*args)
+        pallas = conv2d_int8(*args, interpret=True)
         np.testing.assert_array_equal(np.asarray(pallas), want)
 
     @settings(max_examples=10, deadline=None)
@@ -94,7 +94,7 @@ class TestKernelParitySweep:
         tiled = _fc_int8(*ja, perfmodel.KERNEL_GEMM_TILED)
         np.testing.assert_array_equal(np.asarray(tiled).reshape(-1),
                                       want.reshape(-1))
-        pallas = fc_int8(*ja)
+        pallas = fc_int8(*ja, interpret=True)
         np.testing.assert_array_equal(np.asarray(pallas).reshape(-1),
                                       want.reshape(-1))
 
@@ -134,6 +134,12 @@ class TestSelectKernel:
     def test_tpu_profile_prefers_fused_pallas(self):
         ch = perfmodel.select_kernel(_conv_desc(2304), backend="tpu")
         assert ch.kernel == perfmodel.KERNEL_PALLAS
+
+    def test_unknown_platform_has_no_profile(self, monkeypatch):
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "npu")
+        with pytest.raises(ValueError, match="no backend profile.*'npu'"):
+            perfmodel.default_backend()
 
     def test_forcing_exact_past_bound_raises(self):
         with pytest.raises(ValueError, match="not bit-exact"):
@@ -240,13 +246,15 @@ class TestNetworkParity:
     def test_linuxstack_parity_and_hoisted_binding(self, largek_art):
         ex = create_executor("linuxstack", largek_art)
         ref = create_executor("ref", largek_art)
+        # binding is resolved once at construction, not re-parsed per run
+        bound = ex._params
+        assert all(bool(p) == (d.unit in ("CONV", "FC"))
+                   for (d, _), p in zip(ex._ops, bound))
         x = np.random.default_rng(3).normal(
             0, 1, (8, 8, 8)).astype(np.float32)
         np.testing.assert_array_equal(ex.run(x).output_int8,
                                       ref.run(x).output_int8)
-        # binding is resolved once at construction, not re-parsed per run
-        assert all(("wq" in b) == (d.unit in ("CONV", "FC"))
-                   for d, _, b in ex._ops)
+        assert ex._params is bound
 
 
 # ---------------------------------------------------------------------------

@@ -141,7 +141,7 @@ class TestBf16KernelParitySweep:
                 k, stride, pad, groups, relu)
         gemm = _conv_bf16(*args, perfmodel.KERNEL_GEMM_BF16)
         assert_close(np.asarray(gemm, np.float32), want, tol, "gemm_bf16")
-        pallas = conv2d_bf16(*args)
+        pallas = conv2d_bf16(*args, interpret=True)
         assert_close(np.asarray(pallas, np.float32), want, tol, "pallas_bf16")
 
     @settings(max_examples=8, deadline=None)
@@ -158,7 +158,7 @@ class TestBf16KernelParitySweep:
         gemm = _fc_bf16(*ja, perfmodel.KERNEL_GEMM_BF16)
         assert_close(np.asarray(gemm, np.float32).reshape(-1),
                      want.reshape(-1), tol, "gemm_bf16")
-        pallas = fc_bf16(*ja)
+        pallas = fc_bf16(*ja, interpret=True)
         assert_close(np.asarray(pallas, np.float32).reshape(-1),
                      want.reshape(-1), tol, "pallas_bf16")
 
@@ -186,7 +186,7 @@ class TestBf16KernelParityFixed:
                 k, stride, pad, groups, relu)
         gemm = _conv_bf16(*args, perfmodel.KERNEL_GEMM_BF16)
         assert_close(np.asarray(gemm, np.float32), want, tol, "gemm_bf16")
-        pallas = conv2d_bf16(*args)
+        pallas = conv2d_bf16(*args, interpret=True)
         assert_close(np.asarray(pallas, np.float32), want, tol, "pallas_bf16")
 
     def test_bf16_accumulator_would_fail_the_budget(self):
@@ -226,6 +226,16 @@ class TestBf16KernelSelection:
     def test_tpu_prefers_fused_pallas_bf16(self):
         ch = perfmodel.select_kernel(_conv_desc(1152), backend="tpu",
                                      dtype="bf16")
+        assert ch.kernel == perfmodel.KERNEL_PALLAS_BF16
+
+    @pytest.mark.parametrize("batch", [1, 2, 8])
+    def test_tpu_prefers_fused_pallas_bf16_for_fc(self, batch):
+        # a GEMV is bound by the weight stream, which both kernels pay
+        # alike; the unfused GEMM also moves its f32 accumulator through HBM
+        fc = engine.Descriptor(unit="FC", src_dims=(1, 2048, 1, 1),
+                               dst_dims=(1, 1000, 1, 1), kernel=(1, 1))
+        ch = perfmodel.select_kernel(fc, backend="tpu", dtype="bf16",
+                                     batch=batch)
         assert ch.kernel == perfmodel.KERNEL_PALLAS_BF16
 
     def test_int8_kernel_forced_on_bf16_raises(self):

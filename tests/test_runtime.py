@@ -285,9 +285,10 @@ class TestSession:
         ex = create_executor("baremetal", lenet_art)
         x = np.random.default_rng(8).normal(0, 1, (1, 28, 28)).astype(np.float32)
         first = ex.run(x)
-        arena_after_first = ex._arena_dev
-        assert arena_after_first is not None
-        second = ex.run(x)              # replays over the dirty resident arena
+        params_after_first = ex._params_dev
+        assert params_after_first is not None
+        second = ex.run(x)              # replays over the resident weights
+        assert ex._params_dev is params_after_first
         np.testing.assert_array_equal(first.output_int8, second.output_int8)
         ex.reset_arena()
         third = ex.run(x)
@@ -353,3 +354,93 @@ class TestRegistry:
             ex = api.make_executor(lenet_art, "baremetal")
         ref = Session(lenet_art).run(x)
         np.testing.assert_array_equal(ex.run(x).output_int8, ref.output_int8)
+
+
+# ---------------------------------------------------------------------------
+# Persistent compile cache (entry points only)
+# ---------------------------------------------------------------------------
+class TestCompileCache:
+    @pytest.fixture
+    def cache_config(self):
+        import jax
+        old = jax.config.jax_compilation_cache_dir
+        yield jax.config
+        jax.config.update("jax_compilation_cache_dir", old)
+
+    def test_env_dir_is_kept(self, cache_config, monkeypatch, tmp_path):
+        from repro.runtime.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = cache_config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert cache_config.jax_compilation_cache_dir == before
+
+    def test_default_dir_is_fixed_in_checkout(self, cache_config,
+                                              monkeypatch):
+        import pathlib
+        from repro.runtime.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert enable_compile_cache() == str(root / ".jax_cache")
+        assert cache_config.jax_compilation_cache_dir == str(
+            root / ".jax_cache")
+
+    def test_programs_land_in_env_dir(self, tmp_path):
+        import os
+        import subprocess
+        import sys
+        code = ("from repro.runtime.compile_cache import enable_compile_cache\n"
+                "enable_compile_cache()\n"
+                "import jax\n"
+                "jax.jit(lambda x: x * 3 + 1)(2.0).block_until_ready()\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+                   PYTHONPATH=os.path.join(root, "src"))
+        r = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr
+        assert any(tmp_path.iterdir())
+
+
+# ---------------------------------------------------------------------------
+# Executor dataflow: arena reads resolve to the values last written there
+# ---------------------------------------------------------------------------
+class TestReadPieces:
+    def test_exact_region_forwards_one_value(self):
+        from repro.core.executor import _read_pieces
+        writes = [(-1, 0, 64), (0, 64, 128)]
+        assert _read_pieces(writes, 64, 128, 1) == [(0, 0, 64)]
+
+    def test_concat_reads_producers_side_by_side(self):
+        from repro.core.executor import _read_pieces
+        writes = [(-1, 0, 64), (0, 64, 96), (1, 96, 160)]
+        assert _read_pieces(writes, 64, 160, 2) == [(0, 0, 16), (1, 0, 32)]
+
+    def test_latest_write_wins(self):
+        from repro.core.executor import _read_pieces
+        # op 1 reuses the middle of op 0's surface (liveness-planned arena)
+        writes = [(-1, 0, 64), (0, 64, 192), (1, 96, 128)]
+        assert _read_pieces(writes, 64, 192, 1) == [
+            (0, 0, 32), (1, 0, 32), (0, 64, 128)]
+
+    def test_unwritten_bytes_are_an_error(self):
+        from repro.core.executor import _read_pieces
+        with pytest.raises(ValueError, match="read before"):
+            _read_pieces([(-1, 0, 64)], 32, 96, 1)
+
+    def test_concat_net_batch_matches_single(self):
+        net = graph.NetGraph("cat", (3, 6, 6))
+        net.layer(name="data", type="input", inputs=[])
+        b1 = net.layer(name="b1", type="conv", inputs=["data"],
+                       out_channels=4, kernel=1, relu=True)
+        b2 = net.layer(name="b2", type="conv", inputs=["data"],
+                       out_channels=5, kernel=3, pad=1, relu=True)
+        x = net.layer(name="cat", type="concat", inputs=[b1, b2])
+        x = net.layer(name="gap", type="pool", inputs=[x], pool_mode="gap")
+        net.layer(name="fc", type="fc", inputs=[x], out_channels=3)
+        art = pipeline.CompilerPipeline(net.infer_shapes()).run()
+        ex = create_executor("baremetal", art, native_batch="force")
+        X = np.random.default_rng(3).normal(0, 1, (4, 3, 6, 6)) \
+            .astype(np.float32)
+        single = np.stack([ex.run(x).output_int8 for x in X])
+        np.testing.assert_array_equal(ex.run_batch(X).output_int8, single)
