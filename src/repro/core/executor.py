@@ -43,6 +43,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import engine, intmath, perfmodel, quant
 from repro.core.tracegen import Trace
 from repro.kernels import bf16_conv, int8_conv
+from repro.obs.trace import launch_phases
 
 
 # ---------------------------------------------------------------------------
@@ -105,12 +106,14 @@ _im2col = intmath.im2col
 
 
 def _conv_int8(x, wq, bias, words, k, stride, pad, groups, relu,
-               kernel: str = perfmodel.KERNEL_GEMM_TILED):
+               kernel: str = perfmodel.KERNEL_GEMM_TILED,
+               name: Optional[str] = None):
     if kernel == perfmodel.KERNEL_PALLAS:
         # whole CONV->SDP pipeline fused in the Pallas kernel (epilogue
         # included) — the int32 accumulator never leaves VMEM
         return int8_conv.conv2d_int8(x, wq, bias, words, k, stride, pad,
-                                     groups, relu, interpret=_pallas_interpret())
+                                     groups, relu,
+                                     interpret=_pallas_interpret(), name=name)
     kk = wq.shape[0]
     c, h, w_in = x.shape
     p = (h + 2 * pad - k) // stride + 1
@@ -130,17 +133,20 @@ def _conv_int8(x, wq, bias, words, k, stride, pad, groups, relu,
 
 
 def _fc_int8(x, wq, bias, words, relu,
-             kernel: str = perfmodel.KERNEL_GEMM_TILED):
+             kernel: str = perfmodel.KERNEL_GEMM_TILED,
+             name: Optional[str] = None):
     if kernel == perfmodel.KERNEL_PALLAS:
         return int8_conv.fc_int8(x.reshape(-1), wq, bias, words, relu,
-                                 interpret=_pallas_interpret())
+                                 interpret=_pallas_interpret(),
+                                 name=name)
     acc = _dot_i8(wq, x.reshape(-1, 1), (((1,), (0,)), ((), ())),
                   int(wq.shape[1]), kernel)
     return intmath.row_epilogue(acc, bias, words, relu).reshape(-1, 1, 1)
 
 
 def _conv_int8_batch(xs, wq, bias, words, k, stride, pad, groups, relu,
-                     kernel: str = perfmodel.KERNEL_GEMM_TILED):
+                     kernel: str = perfmodel.KERNEL_GEMM_TILED,
+                     name: Optional[str] = None):
     """Natively batched CONV twin: (B,C,H,W) -> (B,K,P,Q) as ONE GEMM/launch.
 
     The lanes fold onto the GEMM's N axis (column index = lane * PQ + pos),
@@ -153,7 +159,8 @@ def _conv_int8_batch(xs, wq, bias, words, k, stride, pad, groups, relu,
     if kernel == perfmodel.KERNEL_PALLAS:
         return int8_conv.conv2d_int8_batch(xs, wq, bias, words, k, stride,
                                            pad, groups, relu,
-                                           interpret=_pallas_interpret())
+                                           interpret=_pallas_interpret(),
+                                           name=name)
     b, c, h, w_in = xs.shape
     kk = wq.shape[0]
     p = (h + 2 * pad - k) // stride + 1
@@ -177,13 +184,15 @@ def _conv_int8_batch(xs, wq, bias, words, k, stride, pad, groups, relu,
 
 
 def _fc_int8_batch(xs, wq, bias, words, relu,
-                   kernel: str = perfmodel.KERNEL_GEMM_TILED):
+                   kernel: str = perfmodel.KERNEL_GEMM_TILED,
+                   name: Optional[str] = None):
     """Natively batched FC twin: the bucket IS the GEMM N axis — (K, Cin)
     streams once against a (Cin, B) activation block instead of B GEMVs."""
     b = xs.shape[0]
     if kernel == perfmodel.KERNEL_PALLAS:
         return int8_conv.fc_int8_batch(xs.reshape(b, -1), wq, bias, words,
-                                       relu, interpret=_pallas_interpret())
+                                       relu, interpret=_pallas_interpret(),
+                                       name=name)
     acc = _dot_i8(wq, xs.reshape(b, -1).T, (((1,), (0,)), ((), ())),
                   int(wq.shape[1]), kernel)
     y = intmath.row_epilogue(acc, bias, words, relu)
@@ -229,12 +238,14 @@ def _add_int8(a, b, word_a, word_b, relu):
 # numpy core/refops.conv_bf16 (the VP), compared under core/tolerances.py.
 # ---------------------------------------------------------------------------
 def _conv_bf16(x, wq, bias, k, stride, pad, groups, relu,
-               kernel: str = perfmodel.KERNEL_GEMM_BF16):
+               kernel: str = perfmodel.KERNEL_GEMM_BF16,
+               name: Optional[str] = None):
     if kernel == perfmodel.KERNEL_PALLAS_BF16:
         # whole CONV->SDP pipeline fused in the Pallas kernel — the f32
         # accumulator never leaves VMEM
         return bf16_conv.conv2d_bf16(x, wq, bias, k, stride, pad, groups,
-                                     relu, interpret=_pallas_interpret())
+                                     relu, interpret=_pallas_interpret(),
+                                     name=name)
     kk = wq.shape[0]
     c, h, w_in = x.shape
     p = (h + 2 * pad - k) // stride + 1
@@ -257,10 +268,12 @@ def _conv_bf16(x, wq, bias, k, stride, pad, groups, relu,
     return acc.astype(jnp.bfloat16).reshape(kk, p, q)
 
 
-def _fc_bf16(x, wq, bias, relu, kernel: str = perfmodel.KERNEL_GEMM_BF16):
+def _fc_bf16(x, wq, bias, relu, kernel: str = perfmodel.KERNEL_GEMM_BF16,
+             name: Optional[str] = None):
     if kernel == perfmodel.KERNEL_PALLAS_BF16:
         return bf16_conv.fc_bf16(x.reshape(-1), wq, bias, relu,
-                                 interpret=_pallas_interpret())
+                                 interpret=_pallas_interpret(),
+                                 name=name)
     acc = jax.lax.dot_general(wq, x.reshape(-1, 1), (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     acc = acc + bias[:, None]
@@ -270,7 +283,8 @@ def _fc_bf16(x, wq, bias, relu, kernel: str = perfmodel.KERNEL_GEMM_BF16):
 
 
 def _conv_bf16_batch(xs, wq, bias, k, stride, pad, groups, relu,
-                     kernel: str = perfmodel.KERNEL_GEMM_BF16):
+                     kernel: str = perfmodel.KERNEL_GEMM_BF16,
+                     name: Optional[str] = None):
     """Natively batched bf16 CONV twin: lanes fold onto the GEMM N axis.
 
     Folding preserves each column's f32 accumulation order, so this is
@@ -279,7 +293,8 @@ def _conv_bf16_batch(xs, wq, bias, k, stride, pad, groups, relu,
     if kernel == perfmodel.KERNEL_PALLAS_BF16:
         return bf16_conv.conv2d_bf16_batch(xs, wq, bias, k, stride, pad,
                                            groups, relu,
-                                           interpret=_pallas_interpret())
+                                           interpret=_pallas_interpret(),
+                                           name=name)
     b, c, h, w_in = xs.shape
     kk = wq.shape[0]
     p = (h + 2 * pad - k) // stride + 1
@@ -307,12 +322,14 @@ def _conv_bf16_batch(xs, wq, bias, k, stride, pad, groups, relu,
 
 
 def _fc_bf16_batch(xs, wq, bias, relu,
-                   kernel: str = perfmodel.KERNEL_GEMM_BF16):
+                   kernel: str = perfmodel.KERNEL_GEMM_BF16,
+                   name: Optional[str] = None):
     """Natively batched bf16 FC twin — one (K, Cin) x (Cin, B) GEMM."""
     b = xs.shape[0]
     if kernel == perfmodel.KERNEL_PALLAS_BF16:
         return bf16_conv.fc_bf16_batch(xs.reshape(b, -1), wq, bias, relu,
-                                       interpret=_pallas_interpret())
+                                       interpret=_pallas_interpret(),
+                                       name=name)
     acc = jax.lax.dot_general(wq, xs.reshape(b, -1).T,
                               (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
@@ -381,9 +398,9 @@ def _gemm_params(d: engine.Descriptor, arena: np.ndarray, base: int,
             arena[so:so + 4 * k].view(np.int32))
 
 
-def _op_fn(d: engine.Descriptor, kernel: str, dtype: str,
+def _op_fn(index: int, d: engine.Descriptor, kernel: str, dtype: str,
            batched: bool = False):
-    """``f(inputs, params) -> y`` for one descriptor.
+    """``f(inputs, params) -> y`` for descriptor ``index`` of the trace.
 
     ``inputs`` holds the source surface (and the EW unit's second operand),
     each shaped (C, H, W); ``params`` is the descriptor's ``_gemm_params``
@@ -391,19 +408,22 @@ def _op_fn(d: engine.Descriptor, kernel: str, dtype: str,
     launch over (B, C, H, W) inputs instead: the lanes fold onto the GEMM N
     axis, so the weights stream once per bucket.  Folding changes neither any
     product nor any column's accumulation order, so it is bit-identical to
-    vmapping the single-image op over the lanes.
+    vmapping the single-image op over the lanes.  A fused kernel is named
+    after the descriptor, ``d<index>_<unit>`` (``d00_conv``), so the compiled
+    program and a profile name each layer.
     """
     r, _ = d.kernel
     bf16 = dtype == "bf16"
+    name = f"d{index:02d}_{d.unit.lower()}"
     if d.unit == "CONV":
         conv = ((_conv_bf16_batch if batched else _conv_bf16) if bf16
                 else (_conv_int8_batch if batched else _conv_int8))
         return lambda ins, p: conv(ins[0], *p, r, d.stride, d.pad, d.groups,
-                                   d.relu, kernel)
+                                   d.relu, kernel, name)
     if d.unit == "FC":
         fc = ((_fc_bf16_batch if batched else _fc_bf16) if bf16
               else (_fc_int8_batch if batched else _fc_int8))
-        return lambda ins, p: fc(ins[0], *p, d.relu, kernel)
+        return lambda ins, p: fc(ins[0], *p, d.relu, kernel, name)
     assert not batched, d.unit
     if d.unit == "PDP":
         if bf16:
@@ -486,8 +506,8 @@ class ExecutorCapabilities:
                            see which code path serves each network.
     ``profileable``      — ``run_profiled``/``run_batch_profiled`` exist:
                            the backend can time each descriptor's kernel
-                           individually (the observability plane's per-layer
-                           sampling consults this before asking).
+                           individually (``obs.report.profile_layers``, the
+                           cost model's calibration, relies on it).
     """
     native_batching: bool = False
     resident_arena: bool = False
@@ -702,8 +722,8 @@ class _ExecutorBase:
         backends); overridden by backends with ``resident_arena``."""
 
     # Backends that can time each descriptor's kernel individually set this
-    # and implement ``run_profiled``; the scheduler consults
-    # ``capabilities().profileable`` before ever calling the profiled path.
+    # and implement ``run_profiled`` (the calibration workflow of
+    # ``obs.report`` runs it; serving never does).
     _profileable = False
 
     def capabilities(self) -> ExecutorCapabilities:
@@ -729,7 +749,7 @@ class _ExecutorBase:
         """``(ExecResult, samples)`` with one per-layer timing sample per
         descriptor: ``{"index", "unit", "kernel", "bucket", "native", "us",
         "t0", "t1"}`` (``t0``/``t1`` are ``time.perf_counter`` bounds, so the
-        tracer can place the kernels on its timeline).  Only meaningful when
+        caller can place the kernels on its timeline).  Only meaningful when
         ``capabilities().profileable`` — the default raises."""
         raise NotImplementedError(
             f"{type(self).__name__} does not support per-layer profiling "
@@ -807,12 +827,13 @@ class BareMetalExecutor(_ExecutorBase):
         last.update((src, len(self.descs)) for src, _, _ in self._out_pieces)
         self._dead_after = [[j for j, li in last.items() if li == i]
                             for i in range(len(self.descs))]
-        self._single_ops = [_op_fn(d, c.kernel, dtype)
-                            for d, c in zip(self.descs, self.kernel_plan)]
+        self._single_ops = [_op_fn(i, d, c.kernel, dtype) for i, (d, c)
+                            in enumerate(zip(self.descs, self.kernel_plan))]
         # per-op jitted closures for the profiled paths, built on first use
         self._profile_fns = None
         self._profile_batch_fns: Dict[int, list] = {}
-        self._fn = jax.jit(functools.partial(self._replay, self._single_ops))
+        self._fn = jax.jit(_named(functools.partial(
+            self._replay, self._single_ops), "serve_single"))
         # Batch programs are built lazily per batch shape from the
         # per-bucket kernel plan: CONV/FC ops whose bucket plan says
         # ``batched`` run as ONE natively batched fused launch; everything
@@ -874,9 +895,10 @@ class BareMetalExecutor(_ExecutorBase):
         forced = self.native_batch == "force"
         plan = self.batched_kernel_plan(n) if native else self.kernel_plan
         bops = []
-        for d, ch, single in zip(self.descs, plan, self._single_ops):
+        for i, (d, ch, single) in enumerate(zip(self.descs, plan,
+                                                self._single_ops)):
             if native and (ch.batched or forced) and d.unit in ("CONV", "FC"):
-                bops.append((_op_fn(d, ch.kernel, self.cfg.dtype,
+                bops.append((_op_fn(i, d, ch.kernel, self.cfg.dtype,
                                     batched=True), ch, True))
             else:
                 bops.append((jax.vmap(single, in_axes=(0, None)), ch, False))
@@ -894,7 +916,7 @@ class BareMetalExecutor(_ExecutorBase):
             replay = jax.shard_map(replay, mesh=sharding.mesh,
                                    in_specs=(P(), sharding.spec),
                                    out_specs=sharding.spec, check_vma=False)
-        return jax.jit(replay)
+        return jax.jit(_named(replay, f"serve_b{n}"))
 
     def _abstract_args(self, batch: Optional[int] = None, sharding=None):
         """``(params, x)`` shapes of the single-image program (``batch`` None)
@@ -913,17 +935,34 @@ class BareMetalExecutor(_ExecutorBase):
         return self._fn.lower(*self._abstract_args()).compile()
 
     def _out(self, y, lanes=None) -> ExecResult:
-        y = np.asarray(y)[:lanes]
-        return self._finish_out(y.view(np.uint8))
+        """Wait for the program's output surface ``y``, fetch it and unpack
+        its first ``lanes`` rows (the ``device_wait`` and ``d2h`` phases;
+        the collector's ``close`` ends ``d2h`` when the call returns)."""
+        phases = launch_phases()
+        jax.block_until_ready(y)
+        phases.mark("device_wait", host=False)
+        out = self._finish_out(np.asarray(y)[:lanes].view(np.uint8))
+        phases.mark("d2h")
+        return out
 
     def run(self, x: np.ndarray) -> ExecResult:
+        """One image through the single-image program.  The call's phases,
+        in order and tiling it, marked into ``obs.trace.launch_phases()``
+        (opened by the caller): ``quantise``, ``h2d``, ``enqueue`` (the
+        program call returning), ``device_wait``, ``d2h``."""
         if not self._ran_single:
             # the single-image program has one fixed shape, so jit compiles
             # it exactly once — on this call
             self._ran_single = True
             self.compile_count += 1
+        phases = launch_phases()
         xq = self._quant_in(x).reshape(-1)
-        return self._out(self._fn(self._ensure_params(), jnp.asarray(xq)))
+        phases.mark("quantise")
+        xs = jnp.asarray(xq)
+        phases.mark("h2d")
+        y = self._fn(self._ensure_params(), xs)
+        phases.mark("enqueue")
+        return self._out(y)
 
     def capabilities(self) -> ExecutorCapabilities:
         return ExecutorCapabilities(native_batching=True, resident_arena=True,
@@ -938,8 +977,10 @@ class BareMetalExecutor(_ExecutorBase):
         individually so every descriptor has a host-visible boundary
         (``block_until_ready``) to time against.  Integer ops are exact under
         any fusion, so the output is bit-identical to ``run`` for int8 — the
-        only cost is losing XLA's cross-op fusion, which is why this path is
-        opt-in (``TraceConfig.profile``) rather than the serving default.
+        only cost is losing XLA's cross-op fusion, which is why this path
+        serves calibration (``obs.report.profile_layers``) and never a
+        request; the served program's kernels are timed by name in a
+        ``jax.profiler`` trace instead.
         """
         if self._profile_fns is None:
             self._profile_fns = [jax.jit(op) for op in self._single_ops]
@@ -985,13 +1026,15 @@ class BareMetalExecutor(_ExecutorBase):
         bucket); the rest vmap the single-image op per lane.  ``lanes`` trims
         the returned results to the first ``lanes`` rows (the rest being
         scheduler padding); the program itself always executes the full
-        padded shape so each bucket size compiles exactly once.
+        padded shape so each bucket size compiles exactly once.  The call's
+        phases are those of ``run``.
         """
         return self._out(self._run_batch_device(X), lanes)
 
     def _run_batch_device(self, X: np.ndarray) -> jax.Array:
         """The batch program's output surface as a device array, placed by
         ``batch_sharding`` when the bucket divides its mesh."""
+        phases = launch_phases()
         X = np.asarray(X)
         n = X.shape[0]
         shard = self.batch_sharding
@@ -1002,10 +1045,22 @@ class BareMetalExecutor(_ExecutorBase):
             fn = self._make_batch_fn(n, shard)
             self._batch_fns[(n, shard)] = fn
             self.compile_count += 1
-        xs = jnp.asarray(self._quant_in(X).reshape(n, -1))
+        xq = self._quant_in(X).reshape(n, -1)
+        phases.mark("quantise")
+        xs = jnp.asarray(xq)
         if shard is not None:
             xs = jax.device_put(xs, shard)
-        return fn(self._ensure_params(), xs)
+        phases.mark("h2d")
+        y = fn(self._ensure_params(), xs)
+        phases.mark("enqueue")
+        return y
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``: jit names its program ``jit_<name>``, and a
+    profile's events after it."""
+    fn.__name__ = name
+    return fn
 
 
 def _flat_op(op, ins, params):
@@ -1033,8 +1088,9 @@ class LinuxStackExecutor(_ExecutorBase):
         # heap running a conv GEMM program whose result is reshaped to
         # (K, P, Q) at its end (the 5x5/2 stem of the stride_pad test net).
         self._ops = [(d, jax.jit(functools.partial(
-            _flat_op, _op_fn(d, ch.kernel, self.cfg.dtype))))
-                     for d, ch in zip(self.descs, self.kernel_plan)]
+            _flat_op, _op_fn(i, d, ch.kernel, self.cfg.dtype))))
+                     for i, (d, ch) in enumerate(zip(self.descs,
+                                                     self.kernel_plan))]
         self._params = None
         self._bound_params()
 

@@ -586,9 +586,10 @@ def calibrate(samples: Sequence[Dict],
               dtype: str = "int8") -> CalibrationProfile:
     """Fit a ``CalibrationProfile`` from measured per-layer profiling samples.
 
-    ``samples`` are the dicts the executors' ``run_profiled`` emits (and the
-    tracer collects): ``{"index", "kernel", "us"}`` plus optional ``bucket``
-    (coalesced lanes, default 1) and ``native`` (batched-launch style).
+    ``samples`` are the dicts the executors' ``run_profiled`` emits
+    (``obs.report.profile_layers``): ``{"index", "kernel", "us"}`` plus
+    optional ``bucket`` (coalesced lanes, default 1) and ``native``
+    (batched-launch style).
     Constants are fitted per kernel family; the global ``us_per_cycle``
     scale comes from the median measured/modeled ratio across every sample,
     so families the run never exercised still predict in microseconds.

@@ -46,14 +46,17 @@ def _bf16_conv_kernel(w_ref, x_ref, bias_ref, o_ref, acc_ref, *,
 
 def bf16_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array, *,
                    relu: bool = False, block_m: int = 128, block_n: int = 128,
-                   block_k: int = 128, interpret: bool = False) -> jax.Array:
+                   block_k: int = 128, interpret: bool = False,
+                   name: str | None = None) -> jax.Array:
     """``bf16((w @ cols) + bias[:,None])`` with f32 accumulate — channels on rows.
 
     w: (M, K) bfloat16 — weights, M = output channels
     cols: (K, N) bfloat16 — im2col'ed activations, N = output positions P*Q
     bias: (M,) float32, entering the kernel as an (M, 1) column (see
     ``int8_conv_gemm``)
-    Shapes must be multiples of the block sizes (ops.py pads).
+    Shapes must be multiples of the block sizes (ops.py pads).  ``name``
+    names the kernel, and so its custom call in the compiled program and
+    its events in a profile.
     """
     m, k = w.shape
     k2, n = cols.shape
@@ -74,4 +77,5 @@ def bf16_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array, *,
         # f32 accumulator tile, persistent across the K loop (CACC analogue)
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name=name,
     )(w, cols, bias.reshape(m, 1))

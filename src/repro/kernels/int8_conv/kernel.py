@@ -49,7 +49,8 @@ def _int8_conv_kernel(w_ref, x_ref, bias_ref, scale_ref, o_ref, acc_ref, *,
 def int8_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array,
                    scale_words: jax.Array, *, relu: bool = False,
                    block_m: int = 128, block_n: int = 128, block_k: int = 128,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False,
+                   name: str | None = None) -> jax.Array:
     """``clip8(requant((w @ cols) + bias[:,None]))`` — channels on rows.
 
     w: (M, K) int8 — weights, M = output channels
@@ -57,7 +58,9 @@ def int8_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array,
     bias: (M,) int32; scale_words: (M,) int32 packed (m,pre,post)
     Shapes must be multiples of the block sizes (ops.py pads).  The per-row
     vectors enter the kernel as (M, 1) columns: Mosaic tiles a 1-D operand
-    differently from XLA's layout for it and refuses the kernel.
+    differently from XLA's layout for it and refuses the kernel.  ``name``
+    names the kernel, and so its custom call in the compiled program and
+    its events in a profile.
     """
     m, k = w.shape
     k2, n = cols.shape
@@ -79,4 +82,5 @@ def int8_conv_gemm(w: jax.Array, cols: jax.Array, bias: jax.Array,
         # int32 accumulator tile, persistent across the K loop (CACC analogue)
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
         interpret=interpret,
+        name=name,
     )(w, cols, bias.reshape(m, 1), scale_words.reshape(m, 1))

@@ -15,6 +15,10 @@ vmapped single-image program.  Folding is bit-exact: GEMM columns are
 independent, so stacking lanes along N changes neither any product nor any
 column's accumulation order, and the fused CONV->SDP epilogue broadcasts per
 *row* (output channel) — identical maths for every lane.
+
+``name`` names the kernel launch after the layer it serves (the executor
+passes ``d07_conv``), so the compiled program and a profile name it; a
+grouped conv's launch for group ``g`` appends ``_g<g>``.
 """
 
 from __future__ import annotations
@@ -27,6 +31,11 @@ from repro.kernels.int8_conv.ref import (conv2d_int8_ref, fc_int8_ref,
                                          im2col)
 
 
+def _group_name(name, g):
+    """A grouped conv's kernel launch for group ``g``: ``<name>_g<g>``."""
+    return None if name is None else f"{name}_g{g}"
+
+
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
     pad = (-x.shape[axis]) % mult
     if pad == 0:
@@ -37,7 +46,7 @@ def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
 
 
 def _fused_gemm(wq, cols, bias, words, relu, block_m, block_n, block_k,
-                interpret):
+                interpret, name=None):
     """Pad operands to block multiples, run the fused kernel, unpad."""
     m, n = wq.shape[0], cols.shape[1]
     wp = _pad_to(_pad_to(wq, block_m, 0), block_k, 1)
@@ -45,12 +54,13 @@ def _fused_gemm(wq, cols, bias, words, relu, block_m, block_n, block_k,
     bp = _pad_to(bias, block_m, 0)
     sp = _pad_to(words, block_m, 0)
     out = int8_conv_gemm(wp, cp, bp, sp, relu=relu, block_m=block_m,
-                         block_n=block_n, block_k=block_k, interpret=interpret)
+                         block_n=block_n, block_k=block_k, interpret=interpret,
+                         name=name)
     return out[:m, :n]
 
 
 def _fused_gemm_batch(wq, cols_b, bias, words, relu, block_m, block_n,
-                      block_k, interpret):
+                      block_k, interpret, name=None):
     """One fused launch over a (B, K, N) column stack -> (B, M, N).
 
     Lanes fold onto the GEMM N axis (column index = lane * N + position), so
@@ -63,7 +73,7 @@ def _fused_gemm_batch(wq, cols_b, bias, words, relu, block_m, block_n,
     m = wq.shape[0]
     folded = jnp.moveaxis(cols_b, 0, 1).reshape(k, b * n)
     out = _fused_gemm(wq, folded, bias, words, relu, block_m, block_n,
-                      block_k, interpret)
+                      block_k, interpret, name)
     return jnp.moveaxis(out.reshape(m, b, n), 0, 1)
 
 
@@ -72,7 +82,8 @@ def conv2d_int8(x: jax.Array, wq: jax.Array, bias: jax.Array,
                 groups: int = 1, relu: bool = False, *,
                 use_kernel: bool = True, block_m: int = 128,
                 block_n: int = 128, block_k: int = 128,
-                interpret: bool = False) -> jax.Array:
+                interpret: bool = False,
+                name: str | None = None) -> jax.Array:
     """Fused CONV+SDP: (C,H,W) int8 -> (K,P,Q) int8, bit-exact vs refops.
 
     x (C,H,W) int8; wq (K, C/g*k*k) int8; bias/words (K,) int32.
@@ -86,7 +97,7 @@ def conv2d_int8(x: jax.Array, wq: jax.Array, bias: jax.Array,
     if groups == 1:
         cols = im2col(x, k, stride, pad)
         out = _fused_gemm(wq, cols, bias, words, relu, block_m, block_n,
-                          block_k, interpret)
+                          block_k, interpret, name)
         return out.reshape(kk, p, q)
     cg, kg = c // groups, kk // groups
     outs = []
@@ -95,20 +106,21 @@ def conv2d_int8(x: jax.Array, wq: jax.Array, bias: jax.Array,
         outs.append(_fused_gemm(wq[g * kg:(g + 1) * kg], cols,
                                 bias[g * kg:(g + 1) * kg],
                                 words[g * kg:(g + 1) * kg], relu,
-                                block_m, block_n, block_k, interpret))
+                                block_m, block_n, block_k, interpret,
+                                _group_name(name, g)))
     return jnp.concatenate(outs, 0).reshape(kk, p, q)
 
 
 def fc_int8(x: jax.Array, wq: jax.Array, bias: jax.Array, words: jax.Array,
             relu: bool = False, *, use_kernel: bool = True,
             block_m: int = 128, block_n: int = 128, block_k: int = 128,
-            interpret: bool = False) -> jax.Array:
+            interpret: bool = False, name: str | None = None) -> jax.Array:
     """Fused FC+SDP: flat int8 input, wq (K_out, Cin) -> (K_out,1,1) int8."""
     if not use_kernel:
         return fc_int8_ref(x, wq, bias, words, relu)
     cols = x.reshape(-1, 1)
     out = _fused_gemm(wq, cols, bias, words, relu, block_m, block_n, block_k,
-                      interpret)
+                      interpret, name)
     return out.reshape(-1, 1, 1)
 
 
@@ -117,7 +129,8 @@ def conv2d_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
                       groups: int = 1, relu: bool = False, *,
                       use_kernel: bool = True, block_m: int = 128,
                       block_n: int = 128, block_k: int = 128,
-                      interpret: bool = False) -> jax.Array:
+                      interpret: bool = False,
+                      name: str | None = None) -> jax.Array:
     """Natively batched fused CONV+SDP: (B,C,H,W) int8 -> (B,K,P,Q) int8.
 
     ONE kernel launch serves the whole bucket — the batch rides the Pallas
@@ -136,7 +149,7 @@ def conv2d_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
     if groups == 1:
         cols = jax.vmap(lambda x: im2col(x, k, stride, pad))(xs)
         out = _fused_gemm_batch(wq, cols, bias, words, relu, block_m,
-                                block_n, block_k, interpret)
+                                block_n, block_k, interpret, name)
         return out.reshape(b, kk, p, q)
     cg, kg = c // groups, kk // groups
     outs = []
@@ -146,7 +159,8 @@ def conv2d_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
         outs.append(_fused_gemm_batch(wq[g * kg:(g + 1) * kg], cols,
                                       bias[g * kg:(g + 1) * kg],
                                       words[g * kg:(g + 1) * kg], relu,
-                                      block_m, block_n, block_k, interpret))
+                                      block_m, block_n, block_k, interpret,
+                                      _group_name(name, g)))
     return jnp.concatenate(outs, 1).reshape(b, kk, p, q)
 
 
@@ -154,7 +168,8 @@ def fc_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
                   words: jax.Array, relu: bool = False, *,
                   use_kernel: bool = True, block_m: int = 128,
                   block_n: int = 128, block_k: int = 128,
-                  interpret: bool = False) -> jax.Array:
+                  interpret: bool = False,
+                  name: str | None = None) -> jax.Array:
     """Natively batched fused FC+SDP: (B, Cin) int8 -> (B, K_out, 1, 1) int8.
 
     The bucket IS the GEMM N axis — the single-image path is a GEMV that
@@ -166,5 +181,5 @@ def fc_int8_batch(xs: jax.Array, wq: jax.Array, bias: jax.Array,
     b = xs.shape[0]
     cols = xs.reshape(b, -1).T
     out = _fused_gemm(wq, cols, bias, words, relu, block_m, block_n, block_k,
-                      interpret)
+                      interpret, name)
     return out.T.reshape(b, -1, 1, 1)

@@ -1,4 +1,4 @@
-"""Request tracing: ids, lifecycle spans, per-layer samples, Chrome export.
+"""Request tracing: ids, lifecycle spans, launch phases, Chrome export.
 
 One ``Tracer`` lives on each ``Session`` and is threaded through the
 scheduler and the HTTP front-end.  Every submitted request gets a trace id
@@ -7,7 +7,11 @@ deterministic every-Nth-request sampler (``TraceConfig.sample_rate``)
 decides which requests additionally record a ``RequestTrace`` — monotonic
 ``time.perf_counter`` spans for queue-wait, coalesce/hold, pad, launch,
 device-execute, retry backoff, plus instant events for the fault paths
-(shed, watchdog fire, arena reset, circuit transitions).  A request whose
+(shed, watchdog fire, arena reset, circuit transitions).  Inside
+``device_execute`` the executor marks the phases of the blocking call
+(``quantise``, ``h2d``, ``enqueue``, ``device_wait``, ``d2h``) into a
+per-thread ``LaunchPhases`` collector that the scheduler sets only for a
+launch holding a traced request.  A request whose
 id was supplied by the client is ALWAYS traced, so a caller can opt a
 specific request into tracing regardless of the sampler.
 
@@ -26,6 +30,7 @@ buffer, and the histogram bins.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -57,22 +62,22 @@ def valid_trace_id(tid: str) -> bool:
     return all(c.isalnum() or c in "._-" for c in tid)
 
 
+# span+event cap per trace (runaway guard)
+_MAX_EVENTS = 512
+
+
 @dataclasses.dataclass(frozen=True)
 class TraceConfig:
-    """Tracing/profiling knobs (``Session(trace=...)``, ``--trace-sample``).
+    """Tracing knobs (``Session(trace=...)``, ``--trace-sample``).
 
     ``sample_rate=N`` traces every Nth request per net (1 = all, 0 = only
-    requests that arrive with a client-supplied trace id); ``profile=True``
-    additionally runs sampled requests through the executors' per-layer
-    profiled path (stepwise kernel timing — slower, for calibration runs).
+    requests that arrive with a client-supplied trace id).
     ``enabled=False`` turns the subsystem off entirely: ids are still
     assigned (the HTTP contract keeps holding) but nothing is recorded.
     """
     enabled: bool = True
     sample_rate: int = 1
-    profile: bool = False
     capacity: int = 256            # completed-trace ring buffer length
-    max_events: int = 512          # span+event cap per trace (runaway guard)
 
     def __post_init__(self):
         if self.sample_rate < 0:
@@ -90,6 +95,101 @@ class Span:
     args: Dict = dataclasses.field(default_factory=dict)
 
 
+class LaunchPhases:
+    """The phase spans of one launch, marked by the executor on the thread
+    that runs it.
+
+    Phases tile the call: ``start`` opens the first phase, each ``mark``
+    closes the open phase and opens the next, and ``close`` ends the last
+    one when the call has returned.  Every span carries ``launch``, the
+    dispatcher's launch number; a host step also carries ``cpu_s``, this
+    thread's CPU seconds over the span (``time.thread_time``), so its wall
+    time less ``cpu_s`` is time spent off the CPU, waiting for the GIL.
+    The CPU clock is read inside the wall-clock bounds, so ``cpu_s`` does
+    not exceed the span where that clock is precise; where it advances in
+    scheduler ticks (10 ms on some virtual machines) one span's ``cpu_s``
+    is a sample, and only sums over many spans are meaningful."""
+
+    __slots__ = ("launch", "spans", "_t", "_cpu")
+
+    def __init__(self, launch: int):
+        self.launch = launch
+        self.spans: List[Span] = []
+        self._t = self._cpu = 0.0
+
+    def start(self) -> float:
+        """Open the first phase; returns its start."""
+        self._t = time.perf_counter()
+        self._cpu = time.thread_time()
+        return self._t
+
+    def mark(self, name: str, host: bool = True) -> None:
+        """End the open phase as ``name`` and open the next."""
+        cpu = time.thread_time()
+        t = time.perf_counter()
+        args = {"launch": self.launch}
+        if host:
+            args["cpu_s"] = cpu - self._cpu
+        self.spans.append(Span(name, self._t, t, args))
+        self._t = t
+        self._cpu = time.thread_time()
+
+    def close(self) -> float:
+        """Extend the last phase to now, the call's return; returns now."""
+        cpu = time.thread_time()
+        t = time.perf_counter()
+        if self.spans:
+            last = self.spans[-1]
+            last.t1 = t
+            if "cpu_s" in last.args:
+                last.args["cpu_s"] += cpu - self._cpu
+        return t
+
+
+class _NoPhases:
+    """The collector of an untraced launch: keeps the call's bounds and
+    records nothing."""
+
+    __slots__ = ()
+    spans = ()
+
+    def start(self) -> float:
+        return time.perf_counter()
+
+    def mark(self, name: str, host: bool = True) -> None:
+        pass
+
+    def close(self) -> float:
+        return time.perf_counter()
+
+
+_NO_PHASES = _NoPhases()
+_thread = threading.local()
+
+
+def launch_phases():
+    """The calling thread's collector for the launch it runs: a
+    ``LaunchPhases`` inside ``collect_launch``, else one that records
+    nothing."""
+    return getattr(_thread, "phases", _NO_PHASES)
+
+
+@contextlib.contextmanager
+def collect_launch(launch: Optional[int]):
+    """Collect, into the yielded ``LaunchPhases``, the phases that code on
+    this thread marks inside the block; ``launch=None`` (an untraced
+    launch) yields the collector that records nothing."""
+    if launch is None:
+        yield _NO_PHASES
+        return
+    phases = LaunchPhases(launch)
+    _thread.phases = phases
+    try:
+        yield phases
+    finally:
+        _thread.phases = _NO_PHASES
+
+
 class RequestTrace:
     """Recorded lifecycle of ONE sampled request.
 
@@ -100,9 +200,9 @@ class RequestTrace:
     """
 
     __slots__ = ("trace_id", "net", "t_start", "t_end", "status", "error",
-                 "profile", "spans", "events", "layers", "finished")
+                 "spans", "events", "finished")
 
-    def __init__(self, trace_id: str, net: str, profile: bool = False,
+    def __init__(self, trace_id: str, net: str,
                  t_start: Optional[float] = None):
         self.trace_id = trace_id
         self.net = net
@@ -110,26 +210,23 @@ class RequestTrace:
         self.t_end = 0.0
         self.status = "pending"
         self.error = ""
-        self.profile = profile
         self.spans: List[Span] = []
         self.events: List[Tuple[str, float, Dict]] = []
-        self.layers: List[Dict] = []
         self.finished = False
 
     def add_span(self, name: str, t0: float, t1: float, **args) -> None:
-        if len(self.spans) < 512 and t1 >= t0:
+        if len(self.spans) < _MAX_EVENTS and t1 >= t0:
             self.spans.append(Span(name, t0, t1, args))
 
+    def add_spans(self, spans: List[Span]) -> None:
+        """Attach spans recorded elsewhere (a launch's phases), shared with
+        the other traced requests of the same launch."""
+        self.spans.extend(spans[:_MAX_EVENTS - len(self.spans)])
+
     def event(self, name: str, t: Optional[float] = None, **args) -> None:
-        if len(self.events) < 512:
+        if len(self.events) < _MAX_EVENTS:
             self.events.append((name, time.perf_counter() if t is None
                                 else t, args))
-
-    def add_layers(self, samples: List[Dict]) -> None:
-        """Attach per-layer kernel samples from a profiled launch."""
-        room = 2048 - len(self.layers)
-        if room > 0:
-            self.layers.extend(samples[:room])
 
     @property
     def duration_us(self) -> float:
@@ -194,8 +291,7 @@ class Tracer:
         sampled = cfg.sample_rate > 0 and n % cfg.sample_rate == 0
         if not (sampled or forced):
             return tid, None
-        return tid, RequestTrace(tid, net, profile=cfg.profile,
-                                 t_start=t_start)
+        return tid, RequestTrace(tid, net, t_start=t_start)
 
     def finish(self, trace: Optional[RequestTrace], status: str = "ok",
                error: str = "") -> None:
@@ -325,16 +421,6 @@ class Tracer:
                                "cat": "request", "name": name,
                                "ts": self._rel_us(t),
                                "args": dict(args, trace_id=tr.trace_id)})
-            for ly in tr.layers:
-                ev = {"ph": "X", "pid": 1, "tid": tid, "cat": "kernel",
-                      "name": f"{ly.get('unit', '?')}"
-                              f"#{ly.get('index', '?')}:"
-                              f"{ly.get('kernel', '?')}",
-                      "dur": max(float(ly.get("us", 0.0)), 0.001),
-                      "args": dict(ly, trace_id=tr.trace_id)}
-                ev["ts"] = (self._rel_us(float(ly["t0"])) if "t0" in ly
-                            else self._rel_us(tr.t_start))
-                events.append(ev)
         events.sort(key=lambda e: e.get("ts", 0.0))
         return {"traceEvents": events, "displayTimeUnit": "ms",
                 "otherData": {"source": "repro.obs", "dropped": self.dropped}}

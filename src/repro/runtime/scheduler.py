@@ -62,7 +62,7 @@ import numpy as np
 
 from repro.core import perfmodel
 from repro.core.executor import ExecResult
-from repro.obs.trace import status_for_exception
+from repro.obs.trace import collect_launch, status_for_exception
 
 # EMA of coalesce sizes above which a dispatcher starts holding the head
 # request for stragglers (below it, traffic is effectively solo).
@@ -420,6 +420,7 @@ class _NetDispatcher:
         self._retry_rng = random.Random(f"repro-retry-{name}")
         self._model_ms: Optional[float] = None   # cost-model batch-1 ms
         self._model_ms_known = False
+        self._launches = 0                   # launch attempts, numbered
 
     def _tel_record(self, latency_us: float, status: str,
                     good: Optional[bool] = None) -> None:
@@ -748,28 +749,27 @@ class _NetDispatcher:
 
     def _launch(self, ex, batch: List[_Request], attempt: int = 1,
                 degraded: bool = False) -> tuple:
-        """One supervised execution attempt -> ``(outs, bucket, compiles)``.
+        """One supervised execution attempt, launch number ``_launches`` ->
+        ``(outs, bucket, compiles)``.
 
         Traced requests get a ``device_execute`` span timed inside the
-        launcher worker (bounded by the backend's own blocking), and when a
-        sampled request asked for per-layer profiling on a profileable
-        backend the launch runs the executor's profiled path and attaches
-        the kernel samples to the trace."""
+        launcher worker (bounded by the backend's own blocking) and, nested
+        in it, the phases the executor marks there
+        (``obs.trace.collect_launch``, set only when a request of the batch
+        is traced).  Each launch-level span carries ``launch``; the host
+        steps carry ``cpu_s`` too."""
         k = len(batch)
         bucket = 1
         compiles0 = getattr(ex, "compile_count", 0)
         caps = ex.capabilities()
         traced = [r for r in batch if r.trace is not None]
-        profiled = bool(traced) and caps.profileable \
-            and any(r.trace.profile for r in traced)
+        self._launches += 1
+        launch = self._launches
         if k == 1:
             x = batch[0].x
-            run1 = ex.run_profiled if profiled else ex.run
 
-            def call():
-                t0 = time.perf_counter()
-                res = run1(x)
-                return res, t0, time.perf_counter()
+            def run():
+                return ex.run(x)
         else:
             # bucket-pad only for native batch programs (compile-once
             # shapes); sequential fallbacks would just discard the pad.
@@ -779,29 +779,35 @@ class _NetDispatcher:
                       if caps.native_batching else k)
             if caps.max_batch is not None:
                 bucket = min(bucket, caps.max_batch)
-            tp0 = time.perf_counter()
+            # the CPU clock is read inside the wall-clock bounds
+            tp0, cpu0 = time.perf_counter(), time.thread_time()
             padded = pad_batch([r.x for r in batch], bucket)
+            cpu = time.thread_time() - cpu0
             tp1 = time.perf_counter()
             for r in traced:
-                r.trace.add_span("pad", tp0, tp1, bucket=bucket, lanes=k)
+                r.trace.add_span("pad", tp0, tp1, bucket=bucket, lanes=k,
+                                 launch=launch, cpu_s=cpu)
             if caps.shardable:
                 ex.batch_sharding = self.scheduler._lane_sharding(bucket)
-            runk = ex.run_batch_profiled if profiled else ex.run_batch
 
-            def call():
-                t0 = time.perf_counter()
-                res = runk(padded, lanes=k)
-                return res, t0, time.perf_counter()
-        res, t0, t1 = self._launcher.call(call, self._launch_timeout_s(bucket))
-        layers = None
-        if profiled:
-            res, layers = res
+            def run():
+                return ex.run_batch(padded, lanes=k)
+
+        def call():
+            # the phases tile the call: the first opens with it, the last
+            # ends when it returns
+            with collect_launch(launch if traced else None) as phases:
+                t0 = phases.start()
+                res = run()
+                return res, t0, phases.close(), phases.spans
+
+        res, t0, t1, spans = self._launcher.call(
+            call, self._launch_timeout_s(bucket))
         for r in traced:
             r.trace.add_span("device_execute", t0, t1, bucket=bucket,
                              lanes=k, attempt=attempt, degraded=degraded,
-                             profiled=profiled)
-            if layers and r.trace.profile:
-                r.trace.add_layers(layers)
+                             launch=launch)
+            r.trace.add_spans(spans)
         if k == 1:
             outs = [res]
         else:
@@ -854,7 +860,7 @@ class _NetDispatcher:
             self._note_launch_success(degraded)
             self._sync_fault_counter()
             k = len(batch)
-            done = time.perf_counter()
+            done, done_cpu = time.perf_counter(), time.thread_time()
             net.stats.note_dispatch(
                 k, [(done - r.t_submit) * 1e6 for r in batch], bucket=bucket,
                 compiles=compiles, degraded=k if degraded else 0)
@@ -869,7 +875,9 @@ class _NetDispatcher:
                 if r.trace is not None:
                     # recorded before set_result: resolving the future runs
                     # the done-callback that seals this trace
-                    r.trace.add_span("respond", done, time.perf_counter())
+                    cpu = time.thread_time() - done_cpu
+                    r.trace.add_span("respond", done, time.perf_counter(),
+                                     launch=self._launches, cpu_s=cpu)
                 _resolve_future(r.future, r.future.set_result, out)
             self._ema_coalesce = ((1 - _EMA_ALPHA) * self._ema_coalesce
                                   + _EMA_ALPHA * k)
@@ -938,7 +946,8 @@ class Scheduler:
         ``DeadlineExceededError``) order the per-net queue.  Raises
         ``QueueFullError`` when the net's queue is at ``max_queue``.
 
-        Every returned future carries ``fut.trace_id`` when a tracer is
+        Every returned future carries ``fut.trace_id`` and ``fut.trace``
+        (its ``RequestTrace``, or None when unsampled) when a tracer is
         attached; ``trace_id`` (applied to the group's first request)
         forces that request into the sampled set.
         """
@@ -961,6 +970,8 @@ class Scheduler:
                                           trace_id if i == 0 else None,
                                           t_start=now)
                 r.future.trace_id = tid
+                # the HTTP front end adds its decode and encode spans here
+                r.future.trace = trace
                 if trace is not None:
                     r.trace = trace
                     # the future's terminal state — result, exception or

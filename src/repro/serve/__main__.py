@@ -82,9 +82,6 @@ def main(argv=None) -> None:
     ap.add_argument("--trace-sample", type=int, default=1, metavar="N",
                     help="trace every Nth request per net (1 = all, 0 = "
                          "only requests carrying X-Repro-Trace-Id)")
-    ap.add_argument("--profile", action="store_true",
-                    help="run sampled requests through the per-layer "
-                         "profiled path (slower; for calibration runs)")
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
                     help="dump the trace ring buffer as Chrome trace-event "
                          "JSON (DIR/trace.json) on shutdown")
@@ -108,7 +105,6 @@ def main(argv=None) -> None:
     serve_cfg = ServeConfig(fallback_backend=args.fallback_backend,
                             warmup=args.warmup, trace=args.trace,
                             trace_sample=args.trace_sample,
-                            trace_profile=args.profile,
                             trace_dir=args.trace_dir,
                             slo_path=args.slo,
                             slo_period_s=args.slo_period_s)

@@ -30,8 +30,6 @@ class ServeConfig:
                        header contract holds either way).
     ``trace_sample``   — trace every Nth request per net (1 = all, 0 = only
                        requests arriving with an ``X-Repro-Trace-Id``).
-    ``trace_profile``  — run sampled requests through the executors'
-                       per-layer profiled path (slower; calibration runs).
     ``trace_dir``      — dump the trace ring buffer as Chrome trace-event
                        JSON (``<dir>/trace.json``) on shutdown.
     ``slo_path``       — JSON file of ``SloPolicy`` declarations
@@ -45,7 +43,6 @@ class ServeConfig:
     warmup: bool = True
     trace: bool = True
     trace_sample: int = 1
-    trace_profile: bool = False
     trace_dir: Optional[str] = None
     slo_path: Optional[str] = None
     slo_period_s: float = 5.0
@@ -54,5 +51,4 @@ class ServeConfig:
         """The ``repro.obs.TraceConfig`` these knobs describe."""
         from repro.obs.trace import TraceConfig
         return TraceConfig(enabled=self.trace,
-                           sample_rate=self.trace_sample,
-                           profile=self.trace_profile)
+                           sample_rate=self.trace_sample)

@@ -20,7 +20,10 @@ Every inference response carries ``X-Repro-Trace-Id``: the id the request
 arrived with (same header; forces that request into the tracer's sampled
 set) or a server-assigned one.  Error replies (429/503/504/500) carry the
 header too, plus ``error.trace_id`` in the JSON body, so rejected and shed
-requests stay correlatable with their server-side trace.
+requests stay correlatable with their server-side trace.  A traced
+request's trace also gets the handler's ``decode`` span (body read and
+parsed, before its ``request`` span) and ``encode`` span (result encoded
+and written, after it).
 
 Status codes: 400 malformed payload, 404 unknown net/route, 429 queue at
 ``max_queue`` (admission control), 503 circuit open / warming (with
@@ -154,12 +157,14 @@ class ServeHandler(BaseHTTPRequestHandler):
             if not 0 < length <= _MAX_BODY:
                 raise BadRequestError(
                     f"Content-Length must be in (0, {_MAX_BODY}]")
+            t_read = time.perf_counter()
             body = self.rfile.read(length)
             try:
                 x, meta = payload.decode_request(
                     body, self.headers.get("Content-Type", ""))
             except ValueError as e:
                 raise BadRequestError(str(e)) from None
+            t_decoded = time.perf_counter()
             qs = parse_qs(url.query)
             try:
                 priority = int(qs.get("priority", [meta.get("priority", 0)])[0])
@@ -173,10 +178,14 @@ class ServeHandler(BaseHTTPRequestHandler):
                                      deadline_us=deadline_us,
                                      trace_id=trace_id)
             trace_id = getattr(fut, "trace_id", trace_id)
+            trace = getattr(fut, "trace", None)
+            if trace is not None:
+                trace.add_span("decode", t_read, t_decoded)
             res = client.resolve_future(fut,
                                         timeout=client.timeout_for(deadline_us))
+            t_res = time.perf_counter()
             out, ctype = payload.encode_result(
-                net, res, (time.perf_counter() - t0) * 1e6,
+                net, res, (t_res - t0) * 1e6,
                 accept=self.headers.get("Accept", ""))
             extra = {}
             if getattr(res, "degraded", False):
@@ -184,6 +193,9 @@ class ServeHandler(BaseHTTPRequestHandler):
             if trace_id is not None:
                 extra[TRACE_HEADER] = trace_id
             self._reply(200, out, ctype, extra or None)
+            if trace is not None:
+                # after the trace was sealed: the kept object still takes it
+                trace.add_span("encode", t_res, time.perf_counter())
         except ServeError as e:
             # rejections that never reached the scheduler (404/400/warming)
             # still get a fresh id for the error body/header

@@ -10,6 +10,9 @@ Invariants under test:
   * sampling is deterministic (every Nth request per net) and a
     client-supplied trace id always forces tracing;
   * the Chrome trace-event export is schema-valid JSON;
+  * a traced launch's phases nest in and cover its ``device_execute`` span,
+    share one ``launch`` id, and carry ``cpu_s`` on the host steps; an
+    untraced launch collects nothing;
   * the executors' profiled path is bit-exact versus the fused path, and
     ``perfmodel.calibrate`` does not worsen per-layer model error.
 """
@@ -22,6 +25,7 @@ import pytest
 from repro.core import graph, perfmodel, pipeline
 from repro.obs import (RequestTrace, TraceConfig, Tracer, new_trace_id,
                        profile_layers, fidelity_report, valid_trace_id)
+from repro.obs.trace import LaunchPhases, collect_launch, launch_phases
 from repro.runtime import (DeadlineExceededError, QueueFullError, Session,
                            SchedulerConfig, create_executor)
 
@@ -332,21 +336,121 @@ class TestProfiledPath:
         np.testing.assert_array_equal(np.asarray(res.output_int8), want)
         assert all(s["bucket"] == 2 for s in samples)
 
-    def test_profiled_request_attaches_layers(self, tiny_art):
-        ses = Session(tiny_art,
-                      trace=TraceConfig(sample_rate=1, profile=True))
-        try:
-            ses.run(_x())
-            (t,) = ses.tracer.traces()
-            assert len(t.layers) == len(ses.executor().descs)
-            assert all("us" in ly and "kernel" in ly for ly in t.layers)
-        finally:
-            ses.close()
-
     def test_capabilities_gate(self, tiny_ex, tiny_art):
         assert tiny_ex.capabilities().profileable is True
         ref = create_executor("ref", tiny_art)
         assert ref.capabilities().profileable is False
+
+
+# ---------------------------------------------------------------------------
+# Launch phases: the executor's steps inside ``device_execute``
+# ---------------------------------------------------------------------------
+PHASES = ("quantise", "h2d", "enqueue", "device_wait", "d2h")
+HOST_STEPS = ("pad", "quantise", "h2d", "enqueue", "d2h", "respond")
+
+
+@pytest.fixture(scope="module")
+def lenet_art():
+    return pipeline.CompilerPipeline(graph.lenet5()).run()
+
+
+def _lenet_x(i=0):
+    x = np.random.default_rng(i).normal(0, 1, (1, 28, 28))
+    return x.astype(np.float32)
+
+
+class TestLaunchPhases:
+    def test_collector_tiles_and_marks_host_steps(self):
+        ph = LaunchPhases(7)
+        t0 = ph.start()
+        for name in PHASES:
+            ph.mark(name, host=name != "device_wait")
+        d2h_end = ph.spans[-1].t1
+        t1 = ph.close()
+        spans = ph.spans
+        assert [s.name for s in spans] == list(PHASES)
+        assert spans[0].t0 == t0 and spans[-1].t1 == t1 >= d2h_end
+        assert all(a.t1 == b.t0 for a, b in zip(spans, spans[1:]))
+        assert all(s.args["launch"] == 7 for s in spans)
+        assert ["cpu_s" in s.args for s in spans] == \
+            [n != "device_wait" for n in PHASES]
+        assert all(0.0 <= s.args["cpu_s"] <= s.t1 - s.t0 for s in spans
+                   if "cpu_s" in s.args)
+
+    def test_no_collector_outside_a_traced_launch(self):
+        ph = launch_phases()
+        ph.mark("quantise")
+        assert not isinstance(ph, LaunchPhases) and not ph.spans
+        with collect_launch(3) as got:
+            assert launch_phases() is got
+        assert launch_phases() is ph
+        with collect_launch(None) as untraced:
+            assert untraced is ph and launch_phases() is ph
+
+    @pytest.mark.parametrize("n", [1, 3], ids=["bucket1", "batch"])
+    def test_traced_request_gets_phases_inside_device_execute(self,
+                                                              lenet_art, n):
+        ses = Session(lenet_art, trace=TraceConfig(sample_rate=1))
+        try:
+            X = np.stack([_lenet_x(i) for i in range(n)])
+            if n == 1:
+                ses.run(X[0])
+            else:
+                ses.run_batch(X)            # one launch at bucket 4
+            traces = ses.tracer.traces()
+        finally:
+            ses.close()
+        assert len(traces) == n
+        for t in traces:
+            (dx,) = [s for s in t.spans if s.name == "device_execute"]
+            kids = [s for s in t.spans if s.name in PHASES]
+            assert [s.name for s in kids] == list(PHASES)
+            assert all(dx.t0 <= s.t0 <= s.t1 <= dx.t1 for s in kids)
+            covered = sum(s.t1 - s.t0 for s in kids)
+            assert covered >= 0.95 * (dx.t1 - dx.t0)
+            launch = [s for s in t.spans if "launch" in s.args]
+            assert {s.args["launch"] for s in launch} == \
+                {dx.args["launch"]}
+            want = {"device_execute", "respond", *PHASES} | \
+                ({"pad"} if n > 1 else set())
+            assert {s.name for s in launch} == want
+            for s in launch:
+                if s.name in HOST_STEPS:
+                    assert 0.0 <= s.args["cpu_s"] <= s.t1 - s.t0 + 1e-6
+                else:
+                    assert "cpu_s" not in s.args
+
+    @pytest.mark.parametrize("trace", [TraceConfig(sample_rate=2),
+                                       TraceConfig(enabled=False)],
+                             ids=["unsampled", "disabled"])
+    def test_untraced_launch_records_no_phases(self, lenet_art, trace):
+        ses = Session(lenet_art, trace=trace)
+        ex = ses.executor()
+        seen = []
+        run = ex.run
+
+        def spy(x):
+            seen.append(launch_phases())
+            return run(x)
+
+        ex.run = spy
+        try:
+            first = ses.submit(_lenet_x(0))
+            first.result(timeout=120)
+            second = ses.submit(_lenet_x(1))
+            second.result(timeout=120)
+            traces = ses.tracer.traces()
+        finally:
+            ses.close()
+        # sample_rate=2 traces the first request and not the second
+        assert second.trace is None
+        assert [isinstance(c, LaunchPhases) for c in seen] == \
+            [trace.enabled, False]
+        assert [t.trace_id for t in traces] == \
+            ([first.trace_id] if trace.enabled else [])
+        for t in traces:
+            assert len({s.args["launch"] for s in t.spans
+                        if "launch" in s.args}) == 1
 
 
 class TestCalibration:

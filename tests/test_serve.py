@@ -10,6 +10,7 @@ import io
 import json
 import re
 import threading
+import time
 import urllib.error
 import urllib.request
 
@@ -380,6 +381,35 @@ class TestTraceHTTP:
         assert t.status == "ok"
         assert {"queue", "device_execute", "request"} <= \
             {s.name for s in t.spans}
+
+    @pytest.mark.parametrize("npy", [False, True], ids=["json", "npy"])
+    def test_decode_before_and_encode_after_request_span(self, served, npy):
+        base, ses, _ = served
+        x = np.zeros((2, 8, 8), np.float32)
+        if npy:
+            buf = io.BytesIO()
+            np.save(buf, x)
+            body, ctype = buf.getvalue(), "application/x-npy"
+        else:
+            body = json.dumps({"input": x.tolist()}).encode()
+            ctype = "application/json"
+        tid = f"codec-{int(npy)}"
+        _post(f"{base}/v1/infer/tiny", body,
+              {"Content-Type": ctype, "X-Repro-Trace-Id": tid})
+        (t,) = [t for t in ses.tracer.traces() if t.trace_id == tid]
+        # the handler adds ``encode`` after writing the reply
+        deadline = time.monotonic() + 10
+        while "encode" not in {s.name for s in t.spans} \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
+        spans = {s.name: s for s in t.spans}
+        dec, req, enc = spans["decode"], spans["request"], spans["encode"]
+        assert dec.t0 <= dec.t1 <= req.t0
+        # encode starts once the result is out (the dispatcher's respond
+        # ends before it resolves the future, whose callback seals the
+        # request span) and ends with the reply written
+        assert spans["respond"].t1 <= enc.t0 <= enc.t1
+        assert req.t1 <= enc.t1
 
     def test_invalid_trace_id_400(self, served):
         base, _, _ = served

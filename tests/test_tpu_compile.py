@@ -13,6 +13,7 @@ worker imports this module.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +148,24 @@ def test_lenet_bucket8_program_compiles(chip_executor, one_chip):
     txt = ex._make_batch_fn(8).lower(*ex._abstract_args(8, one_chip)) \
         .compile().as_text()
     assert txt.count("tpu_custom_call") >= _n_gemm(ex)
+
+
+def test_lenet_bucket8_program_names_kernels_after_layers(chip_executor,
+                                                          one_chip):
+    """Every fused kernel in the compiled bucket-8 program is named after
+    its descriptor, ``d<index>_<unit>``, and the program ``serve_b8``."""
+    ex = chip_executor
+    txt = ex._make_batch_fn(8).lower(*ex._abstract_args(8, one_chip)) \
+        .compile().as_text()
+    assert txt.startswith("HloModule jit_serve_b8,")
+    calls = [line.split(" = ", 1)[0].strip() for line in txt.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= _n_gemm(ex)
+    assert all(re.fullmatch(r"(ROOT )?%d\d\d_(conv|fc)(\.\d+)?", c)
+               for c in calls), calls
+    gemm = {f"d{i:02d}_{d.unit.lower()}" for i, d in enumerate(ex.descs)
+            if d.unit in ("CONV", "FC")}
+    assert {re.sub(r"^(ROOT )?%|\.\d+$", "", c) for c in calls} == gemm
 
 
 def test_lenet_bucket8_lane_sharded_over_four_chips(chip_executor, topo):
