@@ -203,7 +203,8 @@ def four_chip_phase(seed: int) -> None:
         four = create_executor("baremetal", art)
         four.batch_sharding = lanes
         t0 = time.perf_counter()
-        y = four._run_batch_device(X)
+        launched = four.submit_batch(X)
+        y = launched.y
         y.block_until_ready()
         t_four = time.perf_counter() - t0
         shards = sorted((s.device.id, s.data.shape) for s in
@@ -213,7 +214,7 @@ def four_chip_phase(seed: int) -> None:
               and all(shape[0] == 2 for _, shape in shards),
               f"{name}: output is not split over 4 devices by lane: "
               f"{y.sharding} {shards}")
-        got = four._out(y)
+        got = four.finish(launched)
         if art.cfg.dtype == "int8":
             check(np.array_equal(got.output_int8, want.output_int8),
                   f"{name}: 4-device bucket differs from 1 device")

@@ -508,6 +508,13 @@ class ExecutorCapabilities:
                            the backend can time each descriptor's kernel
                            individually (``obs.report.profile_layers``, the
                            cost model's calibration, relies on it).
+    ``split_launch``     — ``run``/``run_batch`` come in two halves:
+                           ``submit(x)``/``submit_batch(X, lanes)`` enqueue
+                           the program and return a ``Launched`` handle
+                           without waiting for the device, and
+                           ``finish(handle)`` waits and fetches.  The
+                           scheduler enqueues the next launch between the
+                           two halves of the one in flight.
     """
     native_batching: bool = False
     resident_arena: bool = False
@@ -516,6 +523,15 @@ class ExecutorCapabilities:
     dtype: str = "int8"
     kernels: tuple = ()
     profileable: bool = False
+    split_launch: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Launched:
+    """An enqueued launch: the program's output surface on the device (not
+    waited for) and the live ``lanes`` of a batch (None: a single image)."""
+    y: object
+    lanes: Optional[int] = None
 
 
 @runtime_checkable
@@ -934,22 +950,30 @@ class BareMetalExecutor(_ExecutorBase):
         """AOT-compile the fused single-image program (the 'binary')."""
         return self._fn.lower(*self._abstract_args()).compile()
 
-    def _out(self, y, lanes=None) -> ExecResult:
-        """Wait for the program's output surface ``y``, fetch it and unpack
-        its first ``lanes`` rows (the ``device_wait`` and ``d2h`` phases;
-        the collector's ``close`` ends ``d2h`` when the call returns)."""
+    def finish(self, launched: Launched) -> ExecResult:
+        """The second half of a launch: wait for its output surface, fetch
+        it and unpack its first ``lanes`` rows (the ``device_wait`` and
+        ``d2h`` phases; the collector's ``close`` ends ``d2h`` when the
+        call returns)."""
         phases = launch_phases()
-        jax.block_until_ready(y)
+        jax.block_until_ready(launched.y)
         phases.mark("device_wait", host=False)
-        out = self._finish_out(np.asarray(y)[:lanes].view(np.uint8))
+        out = self._finish_out(
+            np.asarray(launched.y)[:launched.lanes].view(np.uint8))
         phases.mark("d2h")
         return out
 
     def run(self, x: np.ndarray) -> ExecResult:
-        """One image through the single-image program.  The call's phases,
-        in order and tiling it, marked into ``obs.trace.launch_phases()``
-        (opened by the caller): ``quantise``, ``h2d``, ``enqueue`` (the
-        program call returning), ``device_wait``, ``d2h``."""
+        """One image through the single-image program: ``submit`` then
+        ``finish``.  The call's phases, in order and tiling it, marked into
+        ``obs.trace.launch_phases()`` (opened by the caller): ``quantise``,
+        ``h2d``, ``enqueue`` (the program call returning), ``device_wait``,
+        ``d2h``."""
+        return self.finish(self.submit(x))
+
+    def submit(self, x: np.ndarray) -> Launched:
+        """The first half of ``run``: quantise, transfer and enqueue one
+        image, without waiting for the device."""
         if not self._ran_single:
             # the single-image program has one fixed shape, so jit compiles
             # it exactly once — on this call
@@ -962,13 +986,13 @@ class BareMetalExecutor(_ExecutorBase):
         phases.mark("h2d")
         y = self._fn(self._ensure_params(), xs)
         phases.mark("enqueue")
-        return self._out(y)
+        return Launched(y)
 
     def capabilities(self) -> ExecutorCapabilities:
         return ExecutorCapabilities(native_batching=True, resident_arena=True,
                                     shardable=True, dtype=self.cfg.dtype,
                                     kernels=self._plan_kernels(),
-                                    profileable=True)
+                                    profileable=True, split_launch=True)
 
     def run_profiled(self, x: np.ndarray) -> tuple:
         """Single-image inference with per-descriptor kernel timing.
@@ -993,7 +1017,7 @@ class BareMetalExecutor(_ExecutorBase):
             self._profile_fns, self._ensure_params(), xq, samples,
             lambda i: {"kernel": self.kernel_plan[i].kernel, "bucket": 1,
                        "native": False})
-        return self._out(y), samples
+        return self.finish(Launched(y)), samples
 
     def run_batch_profiled(self, X: np.ndarray,
                            lanes: Optional[int] = None) -> tuple:
@@ -1015,7 +1039,7 @@ class BareMetalExecutor(_ExecutorBase):
             [fn for fn, _, _ in entry], self._ensure_params(), xs, samples,
             lambda i: {"kernel": entry[i][1].kernel, "bucket": n,
                        "native": entry[i][2]})
-        return self._out(y, lanes), samples
+        return self.finish(Launched(y, lanes)), samples
 
     def run_batch(self, X: np.ndarray,
                   lanes: Optional[int] = None) -> ExecResult:
@@ -1027,13 +1051,15 @@ class BareMetalExecutor(_ExecutorBase):
         the returned results to the first ``lanes`` rows (the rest being
         scheduler padding); the program itself always executes the full
         padded shape so each bucket size compiles exactly once.  The call's
-        phases are those of ``run``.
+        phases are those of ``run``: ``submit_batch`` then ``finish``.
         """
-        return self._out(self._run_batch_device(X), lanes)
+        return self.finish(self.submit_batch(X, lanes))
 
-    def _run_batch_device(self, X: np.ndarray) -> jax.Array:
-        """The batch program's output surface as a device array, placed by
-        ``batch_sharding`` when the bucket divides its mesh."""
+    def submit_batch(self, X: np.ndarray,
+                     lanes: Optional[int] = None) -> Launched:
+        """The first half of ``run_batch``: quantise, transfer and enqueue
+        the batch program, its output placed by ``batch_sharding`` when the
+        bucket divides its mesh, without waiting for the device."""
         phases = launch_phases()
         X = np.asarray(X)
         n = X.shape[0]
@@ -1053,7 +1079,7 @@ class BareMetalExecutor(_ExecutorBase):
         phases.mark("h2d")
         y = fn(self._ensure_params(), xs)
         phases.mark("enqueue")
-        return y
+        return Launched(y, lanes)
 
 
 def _named(fn, name: str):

@@ -11,7 +11,8 @@ device-execute, retry backoff, plus instant events for the fault paths
 ``device_execute`` the executor marks the phases of the blocking call
 (``quantise``, ``h2d``, ``enqueue``, ``device_wait``, ``d2h``) into a
 per-thread ``LaunchPhases`` collector that the scheduler sets only for a
-launch holding a traced request.  A request whose
+launch holding a traced request; a launch split into an enqueue and a
+later wait marks both halves into one collector.  A request whose
 id was supplied by the client is ALWAYS traced, so a caller can opt a
 specific request into tracing regardless of the sampler.
 
@@ -179,10 +180,17 @@ def collect_launch(launch: Optional[int]):
     """Collect, into the yielded ``LaunchPhases``, the phases that code on
     this thread marks inside the block; ``launch=None`` (an untraced
     launch) yields the collector that records nothing."""
-    if launch is None:
-        yield _NO_PHASES
-        return
-    phases = LaunchPhases(launch)
+    with resume_launch(_NO_PHASES if launch is None
+                       else LaunchPhases(launch)) as phases:
+        yield phases
+
+
+@contextlib.contextmanager
+def resume_launch(phases):
+    """Collect into ``phases``, a collector ``collect_launch`` yielded, the
+    phases that code on this thread marks inside the block: the second
+    half of a launch split over two calls, perhaps on another thread, goes
+    on where the first left off."""
     _thread.phases = phases
     try:
         yield phases

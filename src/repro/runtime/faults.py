@@ -131,7 +131,10 @@ class FaultyExecutor:
         setattr(self.inner, "batch_sharding", value)
 
     def capabilities(self):
-        return self.inner.capabilities()
+        # the split halves would reach the inner executor past the
+        # injection point: the wrapper launches whole
+        return dataclasses.replace(self.inner.capabilities(),
+                                   split_launch=False)
 
     def release_hangs(self) -> None:
         """Unblock every call stuck in a ``hang`` fault (they then raise)."""

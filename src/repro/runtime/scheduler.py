@@ -34,6 +34,17 @@ waiting would only add latency; once concurrency is observed it holds the
 head request up to ``max_wait_us`` to let the batch fill towards
 ``max_batch``.
 
+**Two-deep launch pipeline.**  With a backend whose launch comes in two
+halves (``capabilities().split_launch``: enqueue, then wait and fetch), the
+dispatcher enqueues the next launch behind the one on the device whenever
+a full batch is already queued, and only then waits for the first and
+answers it: padding, quantising, transferring and enqueueing batch N+1 run
+while batch N runs.  At most two launches are in flight.  Without a full
+batch queued, or with a backend that launches whole, each launch is waited
+for before the next batch is collected, hold included.  If a launch fails
+with another enqueued behind it, the one behind is dropped unseen and
+launched again once the failed one has gone through its retries.
+
 When several devices are visible and the backend reports
 ``capabilities().shardable``, a coalesced batch whose bucket divides the
 device count is dispatched with its lane axis sharded over a 1-axis data
@@ -62,7 +73,8 @@ import numpy as np
 
 from repro.core import perfmodel
 from repro.core.executor import ExecResult
-from repro.obs.trace import collect_launch, status_for_exception
+from repro.obs.trace import collect_launch, resume_launch, \
+    status_for_exception
 
 # EMA of coalesce sizes above which a dispatcher starts holding the head
 # request for stragglers (below it, traffic is effectively solo).
@@ -315,6 +327,31 @@ def _resolve_future(future: Future, set_fn, value) -> None:
         pass                                # cancelled between check and set
 
 
+@dataclasses.dataclass
+class _Launch:
+    """One launch attempt of a batch, from its pad to its answers.  With a
+    split executor it is in flight between its two halves: enqueued on the
+    device (``handle``) and not yet waited for."""
+    batch: List[_Request]
+    ex: object
+    degraded: bool
+    attempt: int
+    number: int = 0              # the dispatcher's launch number
+    ahead: int = 0               # launches in flight when it was enqueued
+    bucket: int = 1
+    x: object = None             # one image, or the padded batch
+    lanes: Optional[int] = None  # live lanes of a batch; None: one image
+    compiles: int = 0            # program builds its call made
+    handle: object = None        # the executor's ``Launched``
+    phases: object = None        # its phase collector
+    t0: float = 0.0              # start of its ``device_execute`` span
+    exc: Optional[BaseException] = None   # its submit step raised
+
+    @property
+    def traced(self) -> List[_Request]:
+        return [r for r in self.batch if r.trace is not None]
+
+
 def pad_batch(xs: List[np.ndarray], bucket: int) -> np.ndarray:
     """Stack request inputs into a (bucket, ...) batch, zero-padding the tail
     lanes.  Padding changes no live lane's bytes — the batch program is
@@ -410,7 +447,7 @@ class _NetDispatcher:
         self._thread: Optional[threading.Thread] = None
         self._stop = False                   # exit now, cancel queued
         self._drain = False                  # exit once the queue empties
-        self._inflight: List[_Request] = []  # batch currently executing
+        self._inflight: List[_Request] = []  # batches launched, unanswered
         self._ema_coalesce = 1.0
         name = getattr(net, "name", "?")
         self._launcher = _Launcher(name)
@@ -605,29 +642,10 @@ class _NetDispatcher:
                     t_hold1 = time.perf_counter()
                 if self._stop:
                     return None
-                # launch: pop in (priority, deadline) order; shed expired,
-                # push dtype-incompatible requests back for the next pass
-                now = time.perf_counter()
-                head = self._heap[0][1]        # may have changed during hold
-                cap = self._batch_cap(head)
-                batch: List[_Request] = []
-                putback: List[tuple] = []
-                while self._heap and len(batch) < cap:
-                    _, r = heapq.heappop(self._heap)
-                    if r.deadline < now:
-                        expired.append(r)
-                    elif self._compatible(head, r):
-                        batch.append(r)
-                    else:
-                        putback.append((r.sort_key(), r))
-                for item in putback:
-                    heapq.heappush(self._heap, item)
-                self._inflight = list(batch)
-                for r in batch:
-                    if r.trace is not None:
-                        r.trace.add_span("queue", r.t_submit, now,
-                                         coalesced=len(batch))
-                        if t_hold1 > t_hold0:
+                batch = self._pop_batch(expired)
+                if t_hold1 > t_hold0:
+                    for r in batch:
+                        if r.trace is not None:
                             # clamp: a late arrival joined mid-hold, its
                             # wait started at its own submit
                             r.trace.add_span("hold",
@@ -639,6 +657,62 @@ class _NetDispatcher:
             now = time.perf_counter()
             for r in expired:
                 self._shed(r, now)
+
+    def _pop_batch(self, expired: List[_Request]) -> List[_Request]:
+        """Pop the launch's batch (``_cond`` held): in (priority, deadline)
+        order, shedding expired requests into ``expired`` and pushing
+        dtype-incompatible ones back for the next pass; the batch joins
+        ``_inflight``."""
+        now = time.perf_counter()
+        head = self._heap[0][1]
+        cap = self._batch_cap(head)
+        batch: List[_Request] = []
+        putback: List[tuple] = []
+        while self._heap and len(batch) < cap:
+            _, r = heapq.heappop(self._heap)
+            if r.deadline < now:
+                expired.append(r)
+            elif self._compatible(head, r):
+                batch.append(r)
+            else:
+                putback.append((r.sort_key(), r))
+        for item in putback:
+            heapq.heappush(self._heap, item)
+        self._inflight.extend(batch)
+        for r in batch:
+            if r.trace is not None:
+                r.trace.add_span("queue", r.t_submit, now,
+                                 coalesced=len(batch))
+        return batch
+
+    def _full_queued(self) -> bool:
+        """Whether a full batch — ``_batch_cap`` requests of the head's
+        dtype — is queued (``_cond`` held)."""
+        if not self._heap:
+            return False
+        head = self._heap[0][1]
+        same = sum(1 for _, r in self._heap if self._compatible(head, r))
+        return same >= self._batch_cap(head)
+
+    def _take_full(self) -> List[_Request]:
+        """A full batch off the queue for a launch ahead, with no hold, or
+        ``[]`` when none is queued."""
+        expired: List[_Request] = []
+        try:
+            with self._cond:
+                if self._stop or not self._full_queued():
+                    return []
+                return self._pop_batch(expired)
+        finally:
+            now = time.perf_counter()
+            for r in expired:
+                self._shed(r, now)
+
+    def _retire(self, batch: List[_Request]) -> None:
+        """Drop an answered (or failed) batch from ``_inflight``."""
+        gone = {id(r) for r in batch}
+        with self._cond:
+            self._inflight = [r for r in self._inflight if id(r) not in gone]
 
     # -- supervision ---------------------------------------------------------
     def _set_breaker(self, state: str) -> None:
@@ -747,152 +821,276 @@ class _NetDispatcher:
         base = self.config.retry_backoff_s * (2 ** (attempt - 1))
         return base * self._retry_rng.uniform(0.8, 1.2)
 
-    def _launch(self, ex, batch: List[_Request], attempt: int = 1,
-                degraded: bool = False) -> tuple:
-        """One supervised execution attempt, launch number ``_launches`` ->
-        ``(outs, bucket, compiles)``.
+    # -- launches ------------------------------------------------------------
+    def _prepare(self, la: _Launch) -> None:
+        """Number a launch attempt, choose its bucket and pad its inputs.
 
-        Traced requests get a ``device_execute`` span timed inside the
-        launcher worker (bounded by the backend's own blocking) and, nested
-        in it, the phases the executor marks there
-        (``obs.trace.collect_launch``, set only when a request of the batch
-        is traced).  Each launch-level span carries ``launch``; the host
-        steps carry ``cpu_s`` too."""
-        k = len(batch)
-        bucket = 1
-        compiles0 = getattr(ex, "compile_count", 0)
-        caps = ex.capabilities()
-        traced = [r for r in batch if r.trace is not None]
+        Padding is only for native batch programs (compile-once shapes);
+        sequential fallbacks would just discard the pad.  The backend's
+        declared hard ceiling bounds even the padded shape (a
+        non-power-of-two ceiling beats a ladder rung)."""
         self._launches += 1
-        launch = self._launches
+        la.number = self._launches
+        batch, ex = la.batch, la.ex
+        k = len(batch)
         if k == 1:
-            x = batch[0].x
+            la.x = batch[0].x
+            return
+        caps = ex.capabilities()
+        la.bucket = self.config.bucket_for(k) if caps.native_batching else k
+        if caps.max_batch is not None:
+            la.bucket = min(la.bucket, caps.max_batch)
+        la.lanes = k
+        # the CPU clock is read inside the wall-clock bounds
+        tp0, cpu0 = time.perf_counter(), time.thread_time()
+        la.x = pad_batch([r.x for r in batch], la.bucket)
+        cpu = time.thread_time() - cpu0
+        tp1 = time.perf_counter()
+        for r in la.traced:
+            r.trace.add_span("pad", tp0, tp1, bucket=la.bucket, lanes=k,
+                             launch=la.number, cpu_s=cpu)
+        if caps.shardable:
+            ex.batch_sharding = self.scheduler._lane_sharding(la.bucket)
 
-            def run():
-                return ex.run(x)
-        else:
-            # bucket-pad only for native batch programs (compile-once
-            # shapes); sequential fallbacks would just discard the pad.
-            # The backend's declared hard ceiling bounds even the padded
-            # shape (a non-power-of-two ceiling beats a ladder rung).
-            bucket = (self.config.bucket_for(k)
-                      if caps.native_batching else k)
-            if caps.max_batch is not None:
-                bucket = min(bucket, caps.max_batch)
-            # the CPU clock is read inside the wall-clock bounds
-            tp0, cpu0 = time.perf_counter(), time.thread_time()
-            padded = pad_batch([r.x for r in batch], bucket)
-            cpu = time.thread_time() - cpu0
-            tp1 = time.perf_counter()
-            for r in traced:
-                r.trace.add_span("pad", tp0, tp1, bucket=bucket, lanes=k,
-                                 launch=launch, cpu_s=cpu)
-            if caps.shardable:
-                ex.batch_sharding = self.scheduler._lane_sharding(bucket)
-
-            def run():
-                return ex.run_batch(padded, lanes=k)
+    def _run(self, la: _Launch) -> List[ExecResult]:
+        """One blocking, supervised executor call: the whole launch.  The
+        phases tile the call: the first opens with it, the last ends when
+        it returns."""
+        ex = la.ex
+        compiles0 = getattr(ex, "compile_count", 0)
 
         def call():
-            # the phases tile the call: the first opens with it, the last
-            # ends when it returns
-            with collect_launch(launch if traced else None) as phases:
+            with collect_launch(la.number if la.traced else None) as phases:
                 t0 = phases.start()
-                res = run()
-                return res, t0, phases.close(), phases.spans
+                res = (ex.run(la.x) if la.lanes is None
+                       else ex.run_batch(la.x, lanes=la.lanes))
+                return res, phases, t0, phases.close()
 
-        res, t0, t1, spans = self._launcher.call(
-            call, self._launch_timeout_s(bucket))
-        for r in traced:
-            r.trace.add_span("device_execute", t0, t1, bucket=bucket,
-                             lanes=k, attempt=attempt, degraded=degraded,
-                             launch=launch)
-            r.trace.add_spans(spans)
-        if k == 1:
-            outs = [res]
-        else:
-            outs = [ExecResult(output_int8=res.output_int8[i],
-                               output=res.output[i]) for i in range(k)]
-        return outs, bucket, getattr(ex, "compile_count", 0) - compiles0
+        res, la.phases, la.t0, t1 = self._launcher.call(
+            call, self._launch_timeout_s(la.bucket))
+        la.compiles = getattr(ex, "compile_count", 0) - compiles0
+        return self._answers(la, res, t1)
 
-    def _dispatch(self, batch: List[_Request]) -> None:
-        net = self.net
-        attempt = 1
-        traced = [r for r in batch if r.trace is not None]
+    def _submit(self, la: _Launch) -> None:
+        """The first half of a split launch, supervised like the whole
+        call: quantise, transfer and enqueue; the launch is then in flight
+        until ``_finish``."""
+        ex = la.ex
+        compiles0 = getattr(ex, "compile_count", 0)
+
+        def call():
+            with collect_launch(la.number if la.traced else None) as phases:
+                t0 = phases.start()
+                handle = (ex.submit(la.x) if la.lanes is None
+                          else ex.submit_batch(la.x, lanes=la.lanes))
+                return handle, phases, t0
+
+        la.handle, la.phases, la.t0 = self._launcher.call(
+            call, self._launch_timeout_s(la.bucket))
+        la.compiles = getattr(ex, "compile_count", 0) - compiles0
+        if la.ahead:
+            self.net.stats.note_ahead()
+
+    def _finish(self, la: _Launch) -> List[ExecResult]:
+        """The second half of a split launch, supervised like the whole
+        call: wait for the device and fetch, the phases marked into the
+        collector the first half opened."""
+        def call():
+            with resume_launch(la.phases):
+                res = la.ex.finish(la.handle)
+                return res, la.phases.close()
+
+        res, t1 = self._launcher.call(call, self._launch_timeout_s(la.bucket))
+        return self._answers(la, res, t1)
+
+    def _answers(self, la: _Launch, res: ExecResult,
+                 t1: float) -> List[ExecResult]:
+        """Trace the finished launch and split its result by lane.  Traced
+        requests get a ``device_execute`` span from the first half's start
+        to the call's return and, nested in it, the phases the executor
+        marked (``obs.trace.collect_launch``, set only when a request of
+        the batch is traced); ``enqueue`` carries ``ahead``, the launches
+        still in flight when it was enqueued."""
+        for s in la.phases.spans:
+            if s.name == "enqueue":
+                s.args["ahead"] = la.ahead
+        for r in la.traced:
+            r.trace.add_span("device_execute", la.t0, t1, bucket=la.bucket,
+                             lanes=len(la.batch), attempt=la.attempt,
+                             degraded=la.degraded, launch=la.number)
+            r.trace.add_spans(la.phases.spans)
+        if la.lanes is None:
+            return [res]
+        return [ExecResult(output_int8=res.output_int8[i],
+                           output=res.output[i]) for i in range(la.lanes)]
+
+    # -- dispatch ------------------------------------------------------------
+    def _dispatch(self, batch: List[_Request], attempt: int = 1,
+                  split: bool = True) -> Optional[_Launch]:
+        """Launch ``batch``, retrying failed attempts, and answer it.  With
+        ``split``, an executor that has the split and a full batch queued
+        to follow it, the launch is only enqueued and returned in flight,
+        to be settled by ``_settle``; retries run whole."""
         while True:
             ex, degraded = self._route()
+            la = _Launch(batch, ex, degraded, attempt)
             try:
-                outs, bucket, compiles = self._launch(ex, batch, attempt,
-                                                      degraded)
+                self._prepare(la)
+                lead = split and ex.capabilities().split_launch
+                if lead:
+                    with self._cond:
+                        lead = self._full_queued()
+                if lead:
+                    self._submit(la)
+                    return la
+                outs = self._run(la)
             except BaseException as e:  # noqa: BLE001 — forwarded to callers
-                reset = self._note_launch_failure(ex, degraded, e)
-                self._sync_fault_counter()
-                for r in traced:
-                    r.trace.event("launch_failure", attempt=attempt,
-                                  error=type(e).__name__, degraded=degraded)
-                    if isinstance(e, LaunchTimeoutError):
-                        r.trace.event("watchdog_fire",
-                                      timeout_s=e.timeout_s)
-                    if reset:
-                        r.trace.event("arena_reset")
-                with self._cond:
-                    stopping = self._stop
-                if attempt <= self.config.max_retries and not stopping:
-                    # the inputs are still held, so a retry is idempotent;
-                    # an open breaker reroutes the retry to the fallback
-                    net.stats.note_retry()
-                    tb0 = time.perf_counter()
-                    time.sleep(self._backoff_s(attempt))
-                    tb1 = time.perf_counter()
-                    for r in traced:
-                        r.trace.add_span("backoff", tb0, tb1,
-                                         attempt=attempt)
+                if self._failed(ex, degraded, batch, attempt, e):
                     attempt += 1
+                    split = False
                     continue
-                err = BackendFaultError(getattr(net, "name", "?"), attempt, e)
-                err.__cause__ = e
-                now = time.perf_counter()
-                for r in batch:
-                    self._tel_record((now - r.t_submit) * 1e6, "error",
-                                     good=False)
-                    _resolve_future(r.future, r.future.set_exception, err)
-                return
-            self._note_launch_success(degraded)
-            self._sync_fault_counter()
-            k = len(batch)
-            done, done_cpu = time.perf_counter(), time.thread_time()
-            net.stats.note_dispatch(
-                k, [(done - r.t_submit) * 1e6 for r in batch], bucket=bucket,
-                compiles=compiles, degraded=k if degraded else 0)
-            if degraded:
-                outs = [dataclasses.replace(o, degraded=True) for o in outs]
-            for r in batch:
-                lat_us = (done - r.t_submit) * 1e6
-                self._tel_record(lat_us, "degraded" if degraded else "ok",
-                                 good=(not r.deadline_us
-                                       or lat_us <= r.deadline_us))
-            for r, out in zip(batch, outs):
-                if r.trace is not None:
-                    # recorded before set_result: resolving the future runs
-                    # the done-callback that seals this trace
-                    cpu = time.thread_time() - done_cpu
-                    r.trace.add_span("respond", done, time.perf_counter(),
-                                     launch=self._launches, cpu_s=cpu)
-                _resolve_future(r.future, r.future.set_result, out)
-            self._ema_coalesce = ((1 - _EMA_ALPHA) * self._ema_coalesce
-                                  + _EMA_ALPHA * k)
-            return
+                return None
+            self._respond(la, outs)
+            return None
+
+    def _go_ahead(self, flight: _Launch) -> Optional[_Launch]:
+        """Enqueue the next launch behind ``flight``, which is on the device,
+        when the executor has the split and a full batch is queued; its
+        submit step's failure is kept for ``_settle``."""
+        if flight.exc is not None:
+            return None
+        ex, degraded = self._route()
+        if not ex.capabilities().split_launch:
+            return None
+        batch = self._take_full()
+        if not batch:
+            return None
+        la = _Launch(batch, ex, degraded, 1, ahead=1)
+        try:
+            self._prepare(la)
+            self._submit(la)
+        except BaseException as e:  # noqa: BLE001 — settled by _settle
+            la.exc = e
+        return la
+
+    def _settle(self, la: _Launch) -> bool:
+        """Finish an in-flight launch and answer it; a failed one goes
+        through the retry path, run whole.  Returns whether it succeeded."""
+        err = la.exc
+        if err is None:
+            try:
+                outs = self._finish(la)
+            except BaseException as e:  # noqa: BLE001 — forwarded to callers
+                err = e
+        if err is None:
+            self._respond(la, outs)
+            return True
+        if self._failed(la.ex, la.degraded, la.batch, la.attempt, err):
+            self._dispatch(la.batch, la.attempt + 1, split=False)
+        return False
+
+    def _relaunch(self, la: _Launch) -> None:
+        """Launch again, whole, a batch enqueued behind a launch that
+        failed: its output is dropped unseen, and the new attempt counts as
+        a retry, not as a failure."""
+        self.net.stats.note_retry()
+        for r in la.traced:
+            r.trace.event("launch_discarded", launch=la.number)
+        self._dispatch(la.batch, la.attempt + 1, split=False)
+
+    def _failed(self, ex, degraded: bool, batch: List[_Request],
+                attempt: int, exc: BaseException) -> bool:
+        """Record one failed attempt; returns True, after the backoff, when
+        it is to be retried, else resolves the batch's futures with
+        ``BackendFaultError`` and returns False."""
+        net = self.net
+        traced = [r for r in batch if r.trace is not None]
+        reset = self._note_launch_failure(ex, degraded, exc)
+        self._sync_fault_counter()
+        for r in traced:
+            r.trace.event("launch_failure", attempt=attempt,
+                          error=type(exc).__name__, degraded=degraded)
+            if isinstance(exc, LaunchTimeoutError):
+                r.trace.event("watchdog_fire", timeout_s=exc.timeout_s)
+            if reset:
+                r.trace.event("arena_reset")
+        with self._cond:
+            stopping = self._stop
+        if attempt <= self.config.max_retries and not stopping:
+            # the inputs are still held, so a retry is idempotent; an open
+            # breaker reroutes the retry to the fallback
+            net.stats.note_retry()
+            tb0 = time.perf_counter()
+            time.sleep(self._backoff_s(attempt))
+            tb1 = time.perf_counter()
+            for r in traced:
+                r.trace.add_span("backoff", tb0, tb1, attempt=attempt)
+            return True
+        err = BackendFaultError(getattr(net, "name", "?"), attempt, exc)
+        err.__cause__ = exc
+        now = time.perf_counter()
+        for r in batch:
+            self._tel_record((now - r.t_submit) * 1e6, "error", good=False)
+            _resolve_future(r.future, r.future.set_exception, err)
+        return False
+
+    def _respond(self, la: _Launch, outs: List[ExecResult]) -> None:
+        """Count the answered launch and resolve its futures."""
+        net = self.net
+        batch, degraded = la.batch, la.degraded
+        self._note_launch_success(degraded)
+        self._sync_fault_counter()
+        k = len(batch)
+        done, done_cpu = time.perf_counter(), time.thread_time()
+        net.stats.note_dispatch(
+            k, [(done - r.t_submit) * 1e6 for r in batch], bucket=la.bucket,
+            compiles=la.compiles, degraded=k if degraded else 0)
+        if degraded:
+            outs = [dataclasses.replace(o, degraded=True) for o in outs]
+        for r in batch:
+            lat_us = (done - r.t_submit) * 1e6
+            self._tel_record(lat_us, "degraded" if degraded else "ok",
+                             good=(not r.deadline_us
+                                   or lat_us <= r.deadline_us))
+        for r, out in zip(batch, outs):
+            if r.trace is not None:
+                # recorded before set_result: resolving the future runs the
+                # done-callback that seals this trace
+                cpu = time.thread_time() - done_cpu
+                r.trace.add_span("respond", done, time.perf_counter(),
+                                 launch=la.number, cpu_s=cpu)
+            _resolve_future(r.future, r.future.set_result, out)
+        self._ema_coalesce = ((1 - _EMA_ALPHA) * self._ema_coalesce
+                              + _EMA_ALPHA * k)
 
     def _loop(self) -> None:
+        """Collect and launch, two deep: while one launch is on the device
+        the next full batch is enqueued behind it (``_go_ahead``), and only
+        then is the first waited for and answered.  Without a full batch
+        queued the launch in flight is settled first and the next batch
+        collected, hold included."""
+        flight: Optional[_Launch] = None    # enqueued, not yet waited for
         try:
             while True:
-                batch = self._collect()
-                if batch is None:
-                    return
-                if batch:
-                    self._dispatch(batch)
-                with self._cond:
-                    self._inflight = []
+                if flight is None:
+                    batch = self._collect()
+                    if batch is None:
+                        return
+                    if batch:
+                        flight = self._dispatch(batch)
+                        if flight is None:
+                            self._retire(batch)
+                    continue
+                behind = self._go_ahead(flight)
+                ok = self._settle(flight)
+                self._retire(flight.batch)
+                if not ok and behind is not None and behind.exc is None:
+                    # the device may have failed under both: drop the
+                    # output of the launch behind and launch it again
+                    self._relaunch(behind)
+                    self._retire(behind.batch)
+                    behind = None
+                flight = behind
         finally:
             self._launcher.stop()
 

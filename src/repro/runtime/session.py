@@ -86,6 +86,8 @@ class NetStats:
     circuit_state: int = 0       # breaker gauge: 0 closed, 1 half-open, 2 open
     circuit_opens: int = 0       # closed/half-open -> open transitions
     circuit_rejected: int = 0    # submits shed while the circuit was open
+    launches_ahead: int = 0      # launches enqueued behind one still on the
+                                 # device (the two-deep launch pipeline)
     latency_total_us: float = 0.0  # summed submit->result latency: together
     latency_count: int = 0         # with this count, the Prometheus summary
                                    # _sum/_count pair (unwindowed, unlike the
@@ -133,6 +135,10 @@ class NetStats:
             self.latencies_us.extend(latencies_us)
             self.latency_total_us += float(sum(latencies_us))
             self.latency_count += len(latencies_us)
+
+    def note_ahead(self) -> None:
+        with self._lock:
+            self.launches_ahead += 1
 
     def note_warmup(self, ms: float, compiles: int = 0) -> None:
         with self._lock:

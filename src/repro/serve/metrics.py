@@ -56,6 +56,8 @@ _COUNTERS = [
      "Circuit-breaker transitions to open"),
     ("circuit_rejected_total", "circuit_rejected", "counter",
      "Submits shed with 503 while the circuit was open"),
+    ("launches_ahead_total", "launches_ahead", "counter",
+     "Launches enqueued behind one still on the device"),
 ]
 _GAUGES = [
     ("queue_depth_peak", "queue_depth_peak", "gauge",

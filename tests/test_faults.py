@@ -10,6 +10,7 @@ the pristine weight image); an open circuit breaker sheds fast with
 ``degraded=True`` that stay within the repo's parity budgets.
 """
 
+import dataclasses
 import io
 import json
 import threading
@@ -183,7 +184,11 @@ class TestFaultPlanUnits:
         inner = real_ex["baremetal"]
         faulty = FaultyExecutor(inner, FaultPlan(specs=()))
         assert faulty.input_dims == inner.input_dims
-        assert faulty.capabilities() == inner.capabilities()
+        # the same capabilities, but the launch is never split past the
+        # injection point
+        assert faulty.capabilities() == dataclasses.replace(
+            inner.capabilities(), split_launch=False)
+        assert inner.capabilities().split_launch
         assert faulty.arena_ok()             # __getattr__ reaches the arena API
 
 

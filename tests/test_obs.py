@@ -11,13 +11,15 @@ Invariants under test:
     client-supplied trace id always forces tracing;
   * the Chrome trace-event export is schema-valid JSON;
   * a traced launch's phases nest in and cover its ``device_execute`` span,
-    share one ``launch`` id, and carry ``cpu_s`` on the host steps; an
-    untraced launch collects nothing;
+    share one ``launch`` id, and carry ``cpu_s`` on the host steps, also
+    when the launch is enqueued behind another (``enqueue`` carries
+    ``ahead``); an untraced launch collects nothing;
   * the executors' profiled path is bit-exact versus the fused path, and
     ``perfmodel.calibrate`` does not worsen per-layer model error.
 """
 
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -419,6 +421,45 @@ class TestLaunchPhases:
                     assert 0.0 <= s.args["cpu_s"] <= s.t1 - s.t0 + 1e-6
                 else:
                     assert "cpu_s" not in s.args
+
+    def test_overlapped_launch_phases_tile_device_execute(self, lenet_art):
+        """A launch enqueued behind one still on the device keeps its five
+        phases, tiling ``device_execute``; ``enqueue`` carries ``ahead``."""
+        ses = Session(lenet_art, trace=TraceConfig(sample_rate=1),
+                      scheduler=SchedulerConfig(max_batch=2, max_wait_us=0.0,
+                                                adaptive=False))
+        ex = ses.executor()
+        run = ex.run
+        gate, entered = threading.Event(), threading.Event()
+
+        def held(x):                  # the lone first launch waits here
+            entered.set()
+            assert gate.wait(timeout=120)
+            return run(x)
+
+        ex.run = held
+        try:
+            futs = [ses.submit(_lenet_x(0))]
+            assert entered.wait(timeout=120)
+            ex.run = run
+            futs += [ses.submit(_lenet_x(i)) for i in range(1, 5)]
+            gate.set()
+            for f in futs:
+                f.result(timeout=300)
+        finally:
+            ses.close()
+        ahead = {}
+        for f in futs:
+            (dx,) = [s for s in f.trace.spans if s.name == "device_execute"]
+            kids = [s for s in f.trace.spans if s.name in PHASES]
+            assert [s.name for s in kids] == list(PHASES)
+            assert kids[0].t0 == dx.t0 and kids[-1].t1 == dx.t1
+            assert all(a.t1 == b.t0 for a, b in zip(kids, kids[1:]))
+            assert {s.args["launch"] for s in kids} == {dx.args["launch"]}
+            ahead[dx.args["launch"]] = kids[2].args["ahead"]
+        # the lone launch whole, a full batch with another queued, and
+        # that one enqueued behind it
+        assert sorted(ahead.values()) == [0, 0, 1]
 
     @pytest.mark.parametrize("trace", [TraceConfig(sample_rate=2),
                                        TraceConfig(enabled=False)],
