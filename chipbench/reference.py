@@ -19,7 +19,10 @@ Precisions (``forward(..., precision=)``):
             every layer's output rounded to bf16.
   ``f32``   the float network, unrounded (what calibration measures).
 
-Layout: activations (N, C, H, W); conv weights (K, C/g, R, S).
+Layout: activations (N, C, H, W); conv weights (K, C/g, R, S).  A conv's
+``groups`` splits C and K into that many blocks, block j of the output read
+from block j of the input only (``groups == C`` is a depthwise conv).  A
+``concat`` joins its inputs along C, in order.
 """
 
 from __future__ import annotations
@@ -33,11 +36,16 @@ M_MAX = (1 << 15) - 1            # SDP multiplier: 16-bit signed
 # ---------------------------------------------------------------------------
 # Architecture -> layer list
 # ---------------------------------------------------------------------------
+LAYER_TYPES = ("input", "conv", "fc", "pool", "add", "concat")
+
+
 def build(arch: dict) -> list:
     """Topologically ordered layers: dicts with ``name``, ``type`` (input,
-    conv, fc, pool, add), ``inputs`` and the op's sizes.  Two families:
-    ``resnet`` (a 7x7/2 stem, a 3x3/2 max pool, stages of bottleneck blocks,
-    global average pool, one FC) and ``sequential`` (a stated list)."""
+    conv, fc, pool, add, concat), ``inputs`` and the op's sizes.  Three
+    kinds: ``resnet`` (a 7x7/2 stem, a 3x3/2 max pool, stages of bottleneck
+    blocks, global average pool, one FC), ``sequential`` (a stated chain)
+    and ``graph`` (a stated list in this vocabulary, input layer first,
+    checked by ``_check_graph``)."""
     layers = [{"name": "data", "type": "input", "inputs": []}]
 
     def add(**kw):
@@ -45,6 +53,8 @@ def build(arch: dict) -> list:
         return kw["name"]
 
     kind = arch["kind"]
+    if kind == "graph":
+        return _check_graph([dict(l) for l in arch["layers"]])
     if kind == "sequential":
         prev = "data"
         for spec in arch["layers"]:
@@ -82,6 +92,39 @@ def build(arch: dict) -> list:
     return layers
 
 
+def _check_graph(layers: list) -> list:
+    """A ``graph`` kind's list, validated: unique names, known types, the
+    one input layer first, every input an earlier layer, one input for
+    conv, fc and pool, two for add, two or more for concat, and every layer
+    but the last read by a later one (the last is the net's output)."""
+    def err(msg):
+        raise ValueError(f"graph architecture: {msg}")
+
+    if not layers or layers[0]["type"] != "input" or layers[0]["inputs"]:
+        err("the list must start with the input layer, which reads nothing")
+    seen, unread = set(), set()
+    for l in layers:
+        name, t, ins = l["name"], l["type"], l["inputs"]
+        if name in seen:
+            err(f"duplicate layer name {name!r}")
+        if t not in LAYER_TYPES or (t == "input" and seen):
+            err(f"layer {name!r} has type {t!r}")
+        for i in ins:
+            if i not in seen:
+                err(f"layer {name!r} reads {i!r}, not an earlier layer")
+        arity = {"add": len(ins) == 2, "concat": len(ins) >= 2,
+                 "input": True}.get(t, len(ins) == 1)
+        if not arity:
+            err(f"layer {name!r} ({t}) has {len(ins)} inputs")
+        seen.add(name)
+        unread -= set(ins)
+        unread.add(name)
+    if unread != {layers[-1]["name"]}:
+        err(f"layers {sorted(unread - {layers[-1]['name']})} are read by "
+            f"no later layer: the last layer must be the only output")
+    return layers
+
+
 def shapes(layers: list, input_shape) -> dict:
     """name -> (C, H, W) of every layer's output."""
     out = {}
@@ -90,7 +133,19 @@ def shapes(layers: list, input_shape) -> dict:
         if t == "input":
             out[l["name"]] = tuple(input_shape)
             continue
+        if t == "concat":
+            ins = [out[i] for i in l["inputs"]]
+            if any(o[1:] != ins[0][1:] for o in ins):
+                raise ValueError(f"concat {l['name']!r}: inputs of "
+                                 f"different H, W {ins}")
+            out[l["name"]] = (sum(o[0] for o in ins),) + ins[0][1:]
+            continue
         c, h, w = out[l["inputs"][0]]
+        if t == "conv" and (c % l.get("groups", 1)
+                            or l["out"] % l.get("groups", 1)):
+            raise ValueError(f"conv {l['name']!r}: groups "
+                             f"{l.get('groups', 1)} must divide C {c} and "
+                             f"out {l['out']}")
         if t == "conv" or (t == "pool" and l["mode"] != "gap"):
             k, s, p = l["k"], l.get("stride", 1), l.get("pad", 0)
             oh, ow = (h + 2 * p - k) // s + 1, (w + 2 * p - k) // s + 1
@@ -100,6 +155,9 @@ def shapes(layers: list, input_shape) -> dict:
         elif t == "fc":
             out[l["name"]] = (l["out"], 1, 1)
         elif t == "add":
+            if out[l["inputs"][1]] != (c, h, w):
+                raise ValueError(f"add {l['name']!r}: operands of different"
+                                 f" shapes")
             out[l["name"]] = (c, h, w)
         else:
             raise ValueError(t)
@@ -197,8 +255,11 @@ def _windows(x: np.ndarray, k: int, stride: int, pad: int, fill):
 
 
 def _conv_gemm(x: np.ndarray, w2: np.ndarray, k: int, stride: int, pad: int,
-               dtype) -> np.ndarray:
-    """``w2 (K, C*k*k) @ im2col(x)`` per image, in ``dtype`` -> (N, K, P*Q)."""
+               dtype, groups: int = 1) -> np.ndarray:
+    """``w2 (K, C/g*k*k) @ im2col(x)`` per image, in ``dtype`` ->
+    (N, K, P*Q).  With ``groups`` g, one GEMM per group: group j's
+    ``(K/g, C/g*k*k)`` weights against the windows of its own C/g input
+    channels, giving its K/g output channels."""
     n, c = x.shape[:2]
     if k == 1 and pad == 0:
         cols = x[:, :, ::stride, ::stride].astype(dtype)
@@ -209,7 +270,12 @@ def _conv_gemm(x: np.ndarray, w2: np.ndarray, k: int, stride: int, pad: int,
         for i, v in enumerate(wins):
             cols[:, :, i] = v.reshape(n, c, -1)
         cols = cols.reshape(n, c * k * k, p * q)
-    return np.matmul(w2.astype(dtype), cols)
+    if groups == 1:
+        return np.matmul(w2.astype(dtype), cols)
+    kk = w2.shape[0]
+    cols = cols.reshape(n, groups, -1, cols.shape[-1])
+    wg = w2.astype(dtype).reshape(groups, kk // groups, -1)
+    return np.matmul(wg, cols).reshape(n, kk, -1)
 
 
 def quantize_weights(w: np.ndarray, qmax: int, qmin: int) -> tuple:
@@ -259,6 +325,15 @@ def _forward_int(layers, input_shape, params, act_scales, x, precision):
             vals[name] = np.clip(np.round(x / np.float32(sc[name])), qmin,
                                  qmax).astype(np.int64)
             continue
+        if t == "concat":
+            # pure addressing on the engine: every member must already hold
+            # the concat's scale (the program's calibration unifies them)
+            off = [i for i in l["inputs"] if act_scales[i] != act_scales[name]]
+            if off:
+                raise ValueError(f"concat {name!r}: members {off} have "
+                                 f"scales other than the concat's")
+            vals[name] = np.concatenate([vals[i] for i in l["inputs"]], 1)
+            continue
         src = vals[l["inputs"][0]]
         s_in, s_out = sc[l["inputs"][0]], sc[name]
         if t in ("conv", "fc"):
@@ -275,7 +350,7 @@ def _forward_int(layers, input_shape, params, act_scales, x, precision):
                               for a in acc_scales], np.int64)
             if t == "conv":
                 acc = _conv_gemm(src, wq.reshape(kk, -1), k, l["stride"],
-                                 l["pad"], np.float64)
+                                 l["pad"], np.float64, l.get("groups", 1))
                 oh, ow = shp[name][1:]
             else:
                 acc = np.matmul(wq.astype(np.float64),
@@ -335,6 +410,9 @@ def _forward_float(layers, input_shape, params, x, rnd):
         if t == "input":
             vals[name] = rnd(x)
             continue
+        if t == "concat":
+            vals[name] = np.concatenate([vals[i] for i in l["inputs"]], 1)
+            continue
         src = vals[l["inputs"][0]]
         if t in ("conv", "fc"):
             p = params[name]
@@ -342,7 +420,7 @@ def _forward_float(layers, input_shape, params, x, rnd):
             w2 = rnd(p["w"].reshape(kk, -1))
             if t == "conv":
                 acc = _conv_gemm(src, w2, l["k"], l["stride"], l["pad"],
-                                 np.float32)
+                                 np.float32, l.get("groups", 1))
                 oh, ow = shp[name][1:]
             else:
                 acc = np.matmul(w2, src.reshape(src.shape[0], -1, 1))
@@ -378,8 +456,10 @@ def _forward_float(layers, input_shape, params, x, rnd):
 def calibrate(layers: list, input_shape, params: dict, images: np.ndarray,
               percentile: float = 99.99) -> dict:
     """Activation scales ``amax / 127`` per layer, ``amax`` the largest over
-    ``images`` of the ``percentile`` of |activation| in the float network; a
-    max pool keeps its input's scale (it has no requantiser)."""
+    ``images`` of the ``percentile`` of |activation| in the float network,
+    then unified as the program does, in one pass in layer order: a max pool
+    keeps its input's scale (it has no requantiser), and the members of a
+    concat take the concat's (it is pure addressing)."""
     amax = {l["name"]: 1e-8 for l in layers}
     for x in images:
         _, vals = _forward_float(layers, input_shape, params, x[None], _f32)
@@ -390,4 +470,7 @@ def calibrate(layers: list, input_shape, params: dict, images: np.ndarray,
     for l in layers:
         if l["type"] == "pool" and l["mode"] == "max":
             scales[l["name"]] = scales[l["inputs"][0]]
+        if l["type"] == "concat":
+            for i in l["inputs"]:
+                scales[i] = scales[l["name"]]
     return scales

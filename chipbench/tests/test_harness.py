@@ -62,15 +62,16 @@ def lenet_config(engine: str) -> dict:
 
 
 def make_checkout(tmp: pathlib.Path, engine: str = "nv_small",
-                  loop: str = "closed") -> pathlib.Path:
-    """A checkout holding one LeNet-5 cell, with files named only here."""
+                  loop: str = "closed", cfg: dict = None) -> pathlib.Path:
+    """A checkout holding one cell, with files named only here: LeNet-5 at
+    ``engine``, or the configuration ``cfg``."""
     cb = tmp / "chipbench"
     for sub in ("configs", "traffic", "metrics"):
         (cb / sub).mkdir(parents=True)
     for f in (HERE / "metrics").glob("*.py"):
         (cb / "metrics" / f.name).write_text(f.read_text())
     (tmp / "src").symlink_to(REPO / "src")
-    cfg = lenet_config(engine)
+    cfg = cfg or lenet_config(engine)
     (cb / "configs" / "tiny_net.json").write_text(json.dumps(cfg))
     traffic = ({"loop": "closed", "clients": 4, "processes": 2}
                if loop == "closed" else
@@ -163,9 +164,9 @@ def _alter_answer(ex_cls):
 
 def _swap_lanes(orig):
     """Hand each lane of a batch the answer of the next lane."""
-    def broken(self, X, lanes=None):
-        res = orig(self, X, lanes)
-        if res.output_int8.shape[0] > 1:
+    def broken(self, launched):
+        res = orig(self, launched)
+        if res.output_int8.ndim > 1 and res.output_int8.shape[0] > 1:
             res.output_int8 = np.roll(res.output_int8, 1, axis=0)
             res.output = np.roll(res.output, 1, axis=0)
         return res
@@ -187,14 +188,19 @@ def _drop_half(orig):
                                    "half_batch_dropped"])
 def test_broken_timed_path_is_not_correct(tmp_path, on_cpu, capsys,
                                           monkeypatch, fault):
+    """Each fault is planted in a half of a launch, ``submit_batch`` or
+    ``finish``, which a whole launch (``run_batch``) and a split one both
+    call."""
     from repro.core.executor import BareMetalExecutor
     if fault == "answer_altered":
         monkeypatch.setattr(BareMetalExecutor, "_finish_out",
                             _alter_answer(BareMetalExecutor))
+    elif fault == "lanes_swapped":
+        monkeypatch.setattr(BareMetalExecutor, "finish",
+                            _swap_lanes(BareMetalExecutor.finish))
     else:
-        breaks = _swap_lanes if fault == "lanes_swapped" else _drop_half
-        monkeypatch.setattr(BareMetalExecutor, "run_batch",
-                            breaks(BareMetalExecutor.run_batch))
+        monkeypatch.setattr(BareMetalExecutor, "submit_batch",
+                            _drop_half(BareMetalExecutor.submit_batch))
     root = make_checkout(tmp_path)
     res, err = run_cell(root, capsys)
     assert res["correct"] is False, err[-2000:]

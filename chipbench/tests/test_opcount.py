@@ -60,3 +60,31 @@ def test_roofline_floor_of_a_bucket_8_launch():
                                       "bf16", 8, peak)
     assert n_c + n_m == 54
     assert 0.2e-3 < t8 < 0.35e-3 and 0.45e-3 < t16 < 0.65e-3
+
+
+def test_mobilenet_depthwise_layers_are_memory_bound_at_bucket_8():
+    """MobileNet v1 at 224, stated as a ``graph`` list: at bucket 8 on a
+    TPU v5e each of its 13 depthwise 3x3 layers moves more bytes than its
+    few operations can hide, in int8 and in bf16."""
+    from repro.core import graph
+    from test_reference_graph import arch_of
+    arch = arch_of(graph.mobilenet_v1())
+    shape = (3, 224, 224)
+    peak = json.loads((HERE / "peaks.json").read_text())["devices"][
+        "TPU v5 lite"]
+    for dtype in ("int8", "bf16"):
+        costs = opcount.layer_costs(arch, shape, dtype, 8)
+        dw = [(ops, nbytes) for name, ops, nbytes in costs
+              if name.startswith("dw")]
+        assert len(dw) == 13
+        assert all(nbytes / peak["hbm_bytes_per_s"]
+                   > ops / peak["ops_per_s"][dtype] for ops, nbytes in dw)
+        _, n_compute, n_memory = opcount.ideal_seconds(arch, shape, dtype,
+                                                       8, peak)
+        assert n_compute + n_memory == len(costs) == 28
+        assert n_memory >= 13
+    # dw0 by hand: 32 channels, 3x3 stride 1 on 112x112, eight images
+    [(name, ops, nbytes)] = [c for c in opcount.layer_costs(
+        arch, shape, "int8", 8) if c[0] == "dw0"]
+    assert ops == 2 * 8 * 32 * 9 * 112 * 112 == 57_802_752
+    assert nbytes == 32 * 9 + 8 * (2 * 32 * 112 * 112) + 32 * 8
