@@ -21,12 +21,21 @@ reports, and how each is read, is data: ``BENCHMARK.json`` names them and
 Diagnostics go to stderr, ending with each compared number beside its limit.
 The last line of stdout is the JSON result.  A run that finds no TPU, or
 fewer chips than the cell asks for, exits non-zero and prints no result.
+
+A run bounds itself.  Set-up must end within ``SETUP_LIMIT_S`` of process
+start, and the whole run within ``RUN_LIMIT_S``.  Past a bound,
+``faulthandler`` prints every thread's traceback and ends the process with
+exit code 1 and no result.  It acts from a C thread, so it fires even while
+the main thread is inside a compile.  Each set-up stage is named on stderr as
+it starts, so the last lines of a stopped run say where it was.
 """
 
 from __future__ import annotations
 
 import argparse
 import base64
+import faulthandler
+import functools
 import hashlib
 import io
 import json
@@ -52,6 +61,8 @@ import xtrace  # noqa: E402
 
 MARKER = "chipbench_clock_marker"
 TRACE_SLICE_S = 4.0          # profiled seconds, at the end of the window
+SETUP_LIMIT_S = 300.0        # process start -> window start
+RUN_LIMIT_S = 900.0          # process start -> exit
 BUNDLES_KEPT = 6             # per configuration, newest first
 REFERENCE_PROCS = 4
 STATS_KEYS = ("dispatches", "coalesced_images", "compile_count", "rejected",
@@ -64,6 +75,21 @@ class NoChip(RuntimeError):
 
 def log(msg: str) -> None:
     print(f"[chipbench] {msg}", file=sys.stderr, flush=True)
+
+
+def setup_stage(t_proc: float, name: str) -> None:
+    """Name the set-up stage that starts now, with the seconds since process
+    start: a run stopped at its set-up bound ends with the stage it was in,
+    and the differences give each finished stage's seconds."""
+    log(f"set-up: {name} (+{time.monotonic() - t_proc:.1f} s)")
+
+
+def bound_run(t_proc: float, limit_s: float) -> None:
+    """Dump every thread's traceback to stderr and end the process (exit
+    code 1) once ``limit_s`` seconds have passed since process start.
+    Calling it again re-arms the one timer."""
+    faulthandler.dump_traceback_later(t_proc + limit_s - time.monotonic(),
+                                      exit=True, file=sys.__stderr__)
 
 
 def process_start() -> float:
@@ -103,9 +129,10 @@ def sources_digest(root: pathlib.Path) -> str:
 # ---------------------------------------------------------------------------
 # Set-up: bundle, session, server, generators
 # ---------------------------------------------------------------------------
-def load_bundle(root, cfg: dict, seed: int, backend: str) -> tuple:
+def load_bundle(root, cfg: dict, seed: int, backend: str, stage) -> tuple:
     """The served ``Artifacts`` of ``cfg`` at ``seed``, loaded from the
-    bundle cache, or compiled, saved and loaded.  ``(art, how)``."""
+    bundle cache, or compiled, saved and loaded.  ``(art, how)``.
+    ``stage(name)`` is told which of the two it does."""
     from repro.core import engine, graph
     from repro.core.pipeline import Artifacts, CompilerPipeline
     from repro.core.quant import CalibrationTable
@@ -120,7 +147,9 @@ def load_bundle(root, cfg: dict, seed: int, backend: str) -> tuple:
     cache = root / "chipbench" / ".cache" / "bundles"
     bdir = cache / f"{cfg['name']}-{key}"
     if (bdir / "manifest.json").exists():
+        stage("bundle loaded")
         return Artifacts.load(bdir), "loaded"
+    stage("bundle compiling")
     layers = reference.build(cfg["arch"])
     params = reference.make_weights(layers, shape, seed)
     g = getattr(graph, cfg["graph"])()
@@ -168,22 +197,24 @@ def request_tracer(sample_rate: int, keep: bool):
     return WindowTracer(config) if keep else Tracer(config)
 
 
-def warmup(ses, net: str) -> dict:
-    """``Session.warmup`` with each executor call timed: {part: seconds}."""
+def warmup(ses, net: str, stage) -> dict:
+    """``Session.warmup`` with each executor call timed: {part: seconds}.
+    ``stage(name)`` is told of each rung as it starts."""
     ex = ses.executor(net)
     parts = {}
     run1, runb = ex.run, ex.run_batch
 
-    def timed(fn, label):
+    def timed(fn, rung):
         def call(x, *a, **kw):
+            stage(f"warm-up {rung(x)}")
             t = time.perf_counter()
             out = fn(x, *a, **kw)
-            parts[label(x)] = time.perf_counter() - t
+            parts[f"warmup_{rung(x)}_s"] = time.perf_counter() - t
             return out
         return call
 
-    ex.run = timed(run1, lambda x: "warmup_b1_s")
-    ex.run_batch = timed(runb, lambda x: f"warmup_b{len(x)}_s")
+    ex.run = timed(run1, lambda x: "b1")
+    ex.run_batch = timed(runb, lambda x: f"b{len(x)}")
     try:
         ses.warmup(net)
     finally:
@@ -359,6 +390,14 @@ def parse(argv):
 
 def main(argv=None, root: pathlib.Path = ROOT) -> int:
     t_proc = process_start()
+    bound_run(t_proc, SETUP_LIMIT_S)
+    try:
+        return bounded_main(argv, root, t_proc)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+def bounded_main(argv, root: pathlib.Path, t_proc: float) -> int:
     args = parse(argv)
     bench = spec.Benchmark(root)
     cell = bench.cell(args.workload)
@@ -371,6 +410,7 @@ def main(argv=None, root: pathlib.Path = ROOT) -> int:
         root / "chipbench" / ".cache" / "jax")
     os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     sys.path.insert(0, str(root / "src"))
+    setup_stage(t_proc, "jax")
     try:
         devs = require_chips(cell["chips"])
     except NoChip as e:
@@ -398,10 +438,12 @@ def serve_and_measure(root, args, t_proc, cell, cfg, traffic, metrics,
     net = cfg["name"]
     shape = tuple(cfg["input_shape"])
     parts = {"process_to_jax_s": time.monotonic() - t_proc}
+    stage = functools.partial(setup_stage, t_proc)
     t = time.monotonic()
-    art, how = load_bundle(root, cfg, args.seed, jax.default_backend())
+    art, how = load_bundle(root, cfg, args.seed, jax.default_backend(), stage)
     parts[f"bundle_{how}_s"] = time.monotonic() - t
     tracer = request_tracer(srv_cfg["trace_sample"], keep=bool(args.trace))
+    stage("session load")
     t = time.monotonic()
     ses = Session(scheduler=SchedulerConfig(
         max_batch=srv_cfg["max_batch"], max_wait_us=srv_cfg["max_wait_us"],
@@ -409,13 +451,14 @@ def serve_and_measure(root, args, t_proc, cell, cfg, traffic, metrics,
         backend=srv_cfg["backend"], trace=tracer)
     ses.load(art, name=net)
     parts["session_load_s"] = time.monotonic() - t
-    parts.update(warmup(ses, net))
+    parts.update(warmup(ses, net, stage))
     srv = make_server(ses, port=0)
     http_thread = threading.Thread(target=srv.serve_forever,
                                    name="chipbench-http", daemon=True)
     http_thread.start()
     gens = []
     try:
+        stage("generators")
         t = time.monotonic()
         gens = start_generators(traffic, srv.server_address[1], net,
                                 args.seed, shape, args.seconds)
@@ -478,6 +521,7 @@ def measure(root, args, cfg, traffic, ses, net, tracer, gens, parts, t_proc,
     t0 = time.monotonic() + 0.2
     w0 = t0 + traffic["warm_s"]
     w1 = w0 + args.seconds
+    setup_stage(t_proc, "warm traffic")
     for p in gens:
         p.stdin.write(f"{t0!r}\n")
         p.stdin.flush()
@@ -485,9 +529,12 @@ def measure(root, args, cfg, traffic, ses, net, tracer, gens, parts, t_proc,
     s0 = ses.stats(net).snapshot()
     backlog0 = ses.queue_depth(net)
     setup_s = time.monotonic() - t_proc
+    bound_run(t_proc, RUN_LIMIT_S)
     parts["warm_traffic_s"] = traffic["warm_s"]
     log("set-up " + ", ".join(f"{k} {v:.2f}" for k, v in parts.items())
         + f"; setup_s {setup_s:.2f}")
+    log(f"bounds: setup_s {setup_s:.2f} (limit {SETUP_LIMIT_S:.0f}); the "
+        f"whole run ends within {RUN_LIMIT_S:.0f} s of process start")
     trace_dir = None
     if args.trace:
         slice0 = max(w0, w1 - TRACE_SLICE_S)
@@ -559,15 +606,16 @@ def measure(root, args, cfg, traffic, ses, net, tracer, gens, parts, t_proc,
     return rec
 
 
-DISPATCHER_PHASES = ("pad", "device_execute", "respond", "backoff")
+GAP_CAUSES = ("pad", "device_execute", "respond", "backoff", "hold", "queue")
 
 
 def breakdown(rec) -> dict:
     """The device operations that took most time in the traced slice, and
     its longest idle gaps.  Each gap is put down to what the dispatcher was
-    doing: the launch phase (``pad``, ``device_execute``, ``respond``,
-    ``backoff``) whose spans cover most of it; else ``hold``, then ``queue``
-    (requests waiting while nothing launched), then ``request``; else
+    doing: of the launch phases (``pad``, ``device_execute``, ``respond``,
+    ``backoff``), the straggler ``hold`` and ``queue`` (requests waiting
+    outside a hold while nothing launched), the one whose spans cover most
+    of it; else ``request`` where a request was in the server; else
     ``unattributed``."""
     dv = rec["device"]
     ops = sorted(dv["ops"].items(), key=lambda kv: -kv[1])[:10]
@@ -583,10 +631,11 @@ def breakdown(rec) -> dict:
                      xtrace.union(x for x in spans if x[0] < b and x[1] > a))
             if ov > 0:
                 cover[name] = ov
-        launch = {n: v for n, v in cover.items() if n in DISPATCHER_PHASES}
-        label = (max(launch, key=launch.get) if launch else
-                 next((n for n in ("hold", "queue", "request") if n in cover),
-                      "unattributed"))
+        # a hold lies inside its request's queue span
+        cover["queue"] = cover.get("queue", 0.0) - cover.get("hold", 0.0)
+        causes = {n: cover[n] for n in GAP_CAUSES if cover.get(n, 0.0) > 0}
+        label = (max(causes, key=causes.get) if causes else
+                 "request" if "request" in cover else "unattributed")
         gaps.append([f"{label} at +{a:.4f}s", b - a])
     return {"device_ops": [[n, s] for n, s in ops], "idle_gaps": gaps}
 

@@ -34,7 +34,8 @@ def main(out: str) -> None:
 
     run.require_chips(1)
     cfg = json.loads((HERE / "configs" / "resnet50_int8.json").read_text())
-    art, _ = run.load_bundle(HERE.parent, cfg, 0, jax.default_backend())
+    art, _ = run.load_bundle(HERE.parent, cfg, 0, jax.default_backend(),
+                             run.log)
     ex = create_executor("baremetal", art)
     x = np.random.default_rng(0).normal(0, 1, cfg["input_shape"]).astype(
         np.float32)

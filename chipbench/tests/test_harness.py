@@ -14,6 +14,7 @@ import json
 import os
 import pathlib
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -107,8 +108,11 @@ def make_checkout(tmp: pathlib.Path, engine: str = "nv_small",
 
 @pytest.fixture
 def on_cpu(monkeypatch):
-    """Skip the harness's look for a chip: take the CPU device."""
+    """Skip the harness's look for a chip: take the CPU device.  A run
+    starts at its call to ``run.main``, not at the test process's start, so
+    its bounds count from there."""
     import jax
+    monkeypatch.setattr(run, "process_start", time.monotonic)
     monkeypatch.setattr(run, "require_chips", lambda n: jax.devices())
     v5e = run.load_peak("TPU v5 lite")
     monkeypatch.setattr(run, "load_peak", lambda kind: v5e)
@@ -207,8 +211,9 @@ def test_broken_timed_path_is_not_correct(tmp_path, on_cpu, capsys,
     assert res["checks"]["max_diff_steps"]["value"] > 0
 
 
-def test_no_chip_no_result(tmp_path, capsys):
+def test_no_chip_no_result(tmp_path, capsys, monkeypatch):
     """On a platform other than tpu: non-zero exit, no result line."""
+    monkeypatch.setattr(run, "process_start", time.monotonic)
     root = make_checkout(tmp_path)
     rc = run.main(["--workload", "tiny_net.trickle", "--seed", "1",
                    "--seconds", "1", "--trace", "0"], root=root)

@@ -1,6 +1,7 @@
 """The readers of the launch-phase and front-end metrics, on a synthetic
 record: launches grouped by ``launch``, ``host_wait``'s ratio, and idle
-counted only while a request is in the server.
+counted only while a request is in the server.  Also ``run.breakdown``'s
+label of an idle gap.
 
     python -m pytest chipbench/tests/test_readers.py
 """
@@ -15,6 +16,7 @@ import pytest
 HERE = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
 
+import run  # noqa: E402
 import spec  # noqa: E402
 
 
@@ -107,3 +109,29 @@ def test_idle_with_work_counts_idle_only_while_a_request_is_present():
     # the whole idle share is larger: the empty server's idle is left out
     assert got < read("device_idle.open", rec_of(reqs, dict(
         device, busy_s=0.2)))
+
+
+# one idle gap, [1.0, 1.1) s, and the spans around it
+GAP_SPANS = {
+    # 90 % under a hold (inside its queue span), a 10 % sliver of pad
+    "hold": [("request", 0.9, 1.3), ("queue", 0.95, 1.09),
+             ("hold", 1.0, 1.09), ("pad", 1.09, 1.2)],
+    # mostly pad, then the launch; the next request waits in a hold
+    "pad": [("queue", 0.95, 1.0), ("pad", 1.0, 1.08),
+            ("device_execute", 1.08, 1.3), ("queue", 1.07, 1.1),
+            ("hold", 1.07, 1.1), ("request", 0.9, 1.3)],
+    # waiting outside a hold while nothing launched
+    "queue": [("queue", 0.9, 1.095), ("hold", 0.9, 1.02),
+              ("pad", 1.095, 1.2), ("request", 0.9, 1.3)],
+    "request": [("request", 0.9, 1.3), ("encode", 1.2, 1.3)],
+    "unattributed": [("decode", 1.2, 1.25), ("request", 1.25, 1.3)]}
+
+
+@pytest.mark.parametrize("label", sorted(GAP_SPANS))
+def test_breakdown_labels_a_gap_by_what_covers_most_of_it(label):
+    spans = [(n, a, b, {}) for n, a, b in GAP_SPANS[label]]
+    rec = rec_of([spans], device={"ops": {"conv": 0.5},
+                                  "gaps": [(1.0, 1.1)]})
+    got = run.breakdown(rec)
+    assert got["device_ops"] == [["conv", 0.5]]
+    assert [g[0] for g in got["idle_gaps"]] == [f"{label} at +1.0000s"]
